@@ -154,7 +154,6 @@ MATERIALIZE_SCRIPT = r"""
 import json, os, time
 import numpy as np, jax, jax.numpy as jnp
 from functools import partial
-from jax.experimental.shard_map import shard_map
 from jax.sharding import PartitionSpec as P, NamedSharding
 SMOKE = os.environ.get("DISPATCH_SMOKE") == "1"
 
@@ -211,12 +210,14 @@ for (m, chunk) in SIZES:
     rows = jnp.tile(jnp.arange(m, dtype=jnp.int32)[None], (M_DEV, 1))
     for tag, old, new in [("a2a", seq_a2a, batched_a2a),
                           ("ring", seq_ring, batched_ring)]:
-        fo = jax.jit(shard_map(partial(old, m=m), mesh=mesh,
-                               in_specs=(P("model", None), P()),
-                               out_specs=P("model", None), check_rep=False))
-        fn = jax.jit(shard_map(partial(new, m=m), mesh=mesh,
-                               in_specs=(P("model", None), P()),
-                               out_specs=P("model", None), check_rep=False))
+        fo = jax.jit(jax.shard_map(partial(old, m=m), mesh=mesh,
+                                   in_specs=(P("model", None), P()),
+                                   out_specs=P("model", None),
+                                   check_vma=False))
+        fn = jax.jit(jax.shard_map(partial(new, m=m), mesh=mesh,
+                                   in_specs=(P("model", None), P()),
+                                   out_specs=P("model", None),
+                                   check_vma=False))
         np.testing.assert_allclose(np.asarray(fo(buf, rows)),
                                    np.asarray(fn(buf, rows)))
         t_old, t_new = bench(fo, buf, rows), bench(fn, buf, rows)
@@ -230,6 +231,8 @@ print("RESULT " + json.dumps(rows_out))
 
 def _run(script: str, n_devices: int, smoke: bool = False) -> list:
     env = dict(os.environ)
+    # a simulated host-device mesh: pinned to the CPU, never the chip
+    env["JAX_PLATFORMS"] = "cpu"
     env["XLA_FLAGS"] = f"--xla_force_host_platform_device_count={n_devices}"
     env["PYTHONPATH"] = os.path.join(HERE, "..", "src")
     if smoke:

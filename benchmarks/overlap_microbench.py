@@ -54,8 +54,6 @@ import numpy as np                                      # noqa: E402
 HERE = os.path.dirname(os.path.abspath(__file__))
 sys.path.insert(0, os.path.join(HERE, "..", "src"))
 
-from repro.common.compat import install_axis_type_shim  # noqa: E402
-install_axis_type_shim()
 
 import dataclasses                                      # noqa: E402
 from repro.common.config import ModelConfig, MoEConfig  # noqa: E402

@@ -57,10 +57,11 @@ def make_case(seed, b, max_kv, ps):
         pages = [avail.pop() for _ in range(int(pos) // ps + 1)]
         rows.append(PageTable(ps, max_kv, pages).row_idx())
     q = jnp.asarray(rng.standard_normal((b, NQ, HD)) * 0.4, jnp.float32)
+    # head-major pool (nkv, num_rows, hd)
     k = jnp.asarray(rng.standard_normal((num_pages * ps, NKV, HD)) * 0.4,
-                    jnp.float32)
+                    jnp.float32).swapaxes(0, 1)
     v = jnp.asarray(rng.standard_normal((num_pages * ps, NKV, HD)) * 0.6,
-                    jnp.float32)
+                    jnp.float32).swapaxes(0, 1)
     return (q, k, v, jnp.asarray(np.stack(rows)),
             jnp.asarray(positions, jnp.int32))
 
@@ -147,7 +148,7 @@ def smoke():
     np.testing.assert_allclose(np.asarray(out_k), np.asarray(out_x),
                                atol=1e-6, rtol=1e-6)
     poisoned = ops.paged_decode_attention(
-        q, k.at[:ps].set(1e4), v.at[:ps].set(1e4), row_idx, positions,
+        q, k.at[:, :ps].set(1e4), v.at[:, :ps].set(1e4), row_idx, positions,
         page_size=ps)
     np.testing.assert_array_equal(np.asarray(out_k), np.asarray(poisoned))
     print("SMOKE PASSED")

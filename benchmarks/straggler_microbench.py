@@ -25,8 +25,6 @@ import json
 SCRIPT = r"""
 import json, os
 import numpy as np, jax, jax.numpy as jnp
-from repro.common.compat import install_axis_type_shim
-install_axis_type_shim()
 from jax.sharding import PartitionSpec as P, NamedSharding
 from repro.common.config import ModelConfig, MoEConfig
 from repro.core.placement import homogeneous_sharding, ep_materialization
@@ -93,8 +91,6 @@ print("RESULT " + json.dumps(res))
 MTTR_SCRIPT = r"""
 import json, os, tempfile, time, warnings
 import numpy as np, jax
-from repro.common.compat import install_axis_type_shim
-install_axis_type_shim()
 from repro.common import faults
 from repro.common.config import ModelConfig, MoEConfig, TrainConfig
 from repro.core import moe as moe_core
@@ -167,6 +163,8 @@ print("RESULT " + json.dumps(res))
 
 def run(ep=8, t=4096, e=16) -> dict:
     env = dict(os.environ)
+    # a simulated host-device mesh: pinned to the CPU, never the chip
+    env["JAX_PLATFORMS"] = "cpu"
     env["XLA_FLAGS"] = f"--xla_force_host_platform_device_count={ep}"
     env["PYTHONPATH"] = os.path.join(os.path.dirname(__file__), "..", "src")
     env["STRAGGLER_EP"], env["STRAGGLER_T"], env["STRAGGLER_E"] = \
@@ -181,6 +179,8 @@ def run(ep=8, t=4096, e=16) -> dict:
 
 def run_mttr(ep=4, steps=8) -> dict:
     env = dict(os.environ)
+    # a simulated host-device mesh: pinned to the CPU, never the chip
+    env["JAX_PLATFORMS"] = "cpu"
     env["XLA_FLAGS"] = f"--xla_force_host_platform_device_count={ep}"
     env["PYTHONPATH"] = os.path.join(os.path.dirname(__file__), "..", "src")
     env["MTTR_EP"], env["MTTR_STEPS"] = str(ep), str(steps)

@@ -27,8 +27,6 @@ import jax.numpy as jnp
 import numpy as np
 from jax.sharding import NamedSharding, PartitionSpec as P
 
-from repro.common.compat import install_axis_type_shim
-install_axis_type_shim()
 
 from repro.common.config import ModelConfig, MoEConfig
 from repro.core import moe as moe_core

@@ -8,7 +8,8 @@ import repro.configs as configs
 from repro.common.config import TrainConfig
 from repro.core.schedule import ReshardingPolicy
 from repro.data.pipeline import make_stream
-from repro.models.model import Runtime
+from repro.launch.inputs import make_runtime
+from repro.launch.mesh import make_debug_mesh
 from repro.train.trainer import HecateScheduler, train_loop
 
 
@@ -23,7 +24,9 @@ def main():
     # FSSDP step -> feedback; Algorithm 2 re-shards every 20 steps.
     scheduler = HecateScheduler(cfg, ep=1, impl="ep",
                                 resharding=ReshardingPolicy(interval=20))
-    state, history = train_loop(cfg, Runtime(), tc, stream,
+    # a one-device (data, model) mesh runs the sparse FSSDP MoE layer
+    rt = make_runtime(cfg, make_debug_mesh(1, 1), impl="ep")
+    state, history = train_loop(cfg, rt, tc, stream,
                                 scheduler=scheduler, num_steps=40,
                                 log_every=5)
     print(f"\nloss: {history[0]['loss']:.3f} -> {history[-1]['loss']:.3f}")

@@ -37,7 +37,8 @@ from repro.checkpoint import store
 from repro.common.config import TrainConfig
 from repro.core import moe as moe_core
 from repro.data.pipeline import make_stream
-from repro.models.model import Runtime
+from repro.launch.inputs import make_runtime
+from repro.launch.mesh import make_debug_mesh
 from repro.serve.engine import Engine
 from repro.train import step as step_lib
 from repro.train.trainer import HecateScheduler, train_loop
@@ -72,7 +73,7 @@ def main():
     args = ap.parse_args()
 
     cfg = configs.get_smoke("gpt-moe-s").replace(vocab_size=256)
-    rt = Runtime()
+    rt = make_runtime(cfg, make_debug_mesh(1, 1), impl="ep")
     tc = TrainConfig(learning_rate=3e-3, warmup_steps=10,
                      total_steps=args.steps)
     sched = HecateScheduler(cfg, ep=1, impl="ep")

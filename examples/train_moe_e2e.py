@@ -15,7 +15,8 @@ from repro.common.config import ModelConfig, MoEConfig, TrainConfig
 from repro.checkpoint import store
 from repro.core.schedule import ReshardingPolicy
 from repro.data.pipeline import make_stream
-from repro.models.model import Runtime
+from repro.launch.inputs import make_runtime
+from repro.launch.mesh import make_debug_mesh
 from repro.train import step as step_lib
 from repro.train.trainer import HecateScheduler, train_loop
 
@@ -55,7 +56,8 @@ def main():
         if i and i % 100 == 0:
             store.save(args.ckpt_dir, i, {"params": state.params})
 
-    state, hist = train_loop(cfg, Runtime(), tc, stream, scheduler=sched,
+    rt = make_runtime(cfg, make_debug_mesh(1, 1), impl="ep")
+    state, hist = train_loop(cfg, rt, tc, stream, scheduler=sched,
                              num_steps=args.steps, log_every=10, callback=cb)
     store.save(args.ckpt_dir, args.steps, {"params": state.params})
     dt = time.time() - t0

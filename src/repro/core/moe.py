@@ -381,14 +381,6 @@ def gate(cfg: ModelConfig, wr: jnp.ndarray, x: jnp.ndarray,
 # ---------------------------------------------------------------------------
 # SparseAllGather inside shard_map
 # ---------------------------------------------------------------------------
-def _axis_size(name) -> int:
-    """Static size of a shard_map axis.  ``jax.lax.axis_size`` is missing on
-    older JAX; ``psum`` of a literal folds to a static int there."""
-    if hasattr(jax.lax, "axis_size"):
-        return jax.lax.axis_size(name)
-    return jax.lax.psum(1, name)
-
-
 def _materialize(cfg: ModelConfig, buf, pa: PlanArrays, impl: str,
                  ep_axis: str, fsdp_axes, m: int, batch: bool = True):
     """buf: (rows_local, chunk_loc).  Returns (K, chunk_len) full chunks.
@@ -409,7 +401,7 @@ def _materialize(cfg: ModelConfig, buf, pa: PlanArrays, impl: str,
     the CPU backend prefers it; wire volume is identical either way.
     """
     me = jax.lax.axis_index(ep_axis)
-    M = _axis_size(ep_axis)
+    M = jax.lax.axis_size(ep_axis)
     local_rows = pa.local_rows[0]                 # (k_local,)
     owned = jnp.take(buf, local_rows, axis=0)     # (k_local, chunk_loc)
     owned = owned * (pa.local_experts[0][:, None] >= 0).astype(buf.dtype)
@@ -591,7 +583,7 @@ def _moe_body(cfg: ModelConfig, impl: str, ep_axis: str, fsdp_axes,
     collectives entirely.
     """
     me = jax.lax.axis_index(ep_axis)
-    M = _axis_size(ep_axis)
+    M = jax.lax.axis_size(ep_axis)
     T, D = x.shape
     all_axes = tuple(fsdp_axes) + (ep_axis,)
     K = pa.local_rows.shape[-1] + m if impl != "dense" \
@@ -750,7 +742,6 @@ def moe_layer(cfg: ModelConfig, rt: MoERuntime, x, wr, buf,
         return y, MoEAux(counts, aux, z, dropped,
                          counts.sum()[None], jnp.zeros(()))
 
-    from jax.experimental.shard_map import shard_map
     ep = rt.ep_size()
     all_axes = tuple(rt.batch_axes) + (rt.ep_axis,)
     t_loc = x.shape[0] // rt.mesh.shape[rt.ep_axis] // int(
@@ -769,11 +760,11 @@ def moe_layer(cfg: ModelConfig, rt: MoERuntime, x, wr, buf,
     if premat is not None:
         in_specs += (P(rt.ep_axis, None, None),)
         args += (premat.astype(x.dtype),)
-    y, counts, aux, z, dropped, dev_loads, pad_frac = shard_map(
+    y, counts, aux, z, dropped, dev_loads, pad_frac = jax.shard_map(
         body, mesh=rt.mesh,
         in_specs=in_specs,
         out_specs=(P(all_axes, None), P(), P(), P(), P(), P(), P()),
-        check_rep=False,
+        check_vma=False,
     )(*args)
     return y, MoEAux(counts, aux, z, dropped, dev_loads, pad_frac)
 
@@ -970,7 +961,6 @@ def materialize_layer(cfg: ModelConfig, rt: MoERuntime, buf,
     ``moe_layer_regather_pipelined``'s VJP, whose explicit transpose is the
     SparseReduceScatter landing the buffer gradient.
     """
-    from jax.experimental.shard_map import shard_map
     buf, _ = unwrap_buffer(buf)
     buf = buf.astype(dtype or jnp.dtype(cfg.dtype))
     m = _m_of(rt, pa_l)
@@ -981,12 +971,12 @@ def materialize_layer(cfg: ModelConfig, rt: MoERuntime, buf,
                           rt.fsdp_axes, m, batch=batch)
         return ch[None]                              # (1, K, chunk_len)
 
-    out = shard_map(
+    out = jax.shard_map(
         body, mesh=rt.mesh,
         in_specs=(P(rt.ep_axis, rt.fsdp_axes),
                   plan_arrays_specs(rt.mesh, rt.ep_axis)),
         out_specs=P(rt.ep_axis, None, None),
-        check_rep=False)(buf, pa_l)
+        check_vma=False)(buf, pa_l)
     return checkpoint_name(out, "moe_materialized") if name else out
 
 
@@ -1010,7 +1000,6 @@ def materialize_stack(cfg: ModelConfig, rt: MoERuntime, buf, pa: PlanArrays,
     on the owning buffer shards, once per step.  ``materialize_chunks``
     wraps this body in a cached jit for the serving path.
     """
-    from jax.experimental.shard_map import shard_map
     buf, _ = unwrap_buffer(buf)
     dt = jnp.dtype(dtype or jnp.dtype(cfg.dtype))
     m = _m_of(rt, pa)
@@ -1028,11 +1017,11 @@ def materialize_stack(cfg: ModelConfig, rt: MoERuntime, buf, pa: PlanArrays,
 
     specs = plan_arrays_specs(rt.mesh, rt.ep_axis)
     stacked = PlanArrays(*[P(None, *tuple(s)) for s in specs])
-    out = shard_map(
+    out = jax.shard_map(
         body, mesh=rt.mesh,
         in_specs=(P(rt.ep_axis, rt.fsdp_axes), stacked),
         out_specs=P(None, rt.ep_axis, None, None),
-        check_rep=False)(buf, pa)
+        check_vma=False)(buf, pa)
     return checkpoint_name(out, "moe_materialized") if name else out
 
 

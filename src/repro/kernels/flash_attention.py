@@ -7,6 +7,12 @@ paper's contribution is the MoE side.
 
 Grid (B, N, Sq/BQ, Skv/BK), KV innermost; m/l/acc live in VMEM scratch;
 causal and sliding-window tiles outside the mask are skipped entirely.
+
+A pallas_call has no reverse-mode rule, so ``flash_attention_trainable``
+wraps the kernel in a custom VJP: the forward is the kernel, the backward
+recomputes the attention of one batch row at a time with the jnp oracle
+(``repro.kernels.ref.flash_attention_ref``) and pulls the cotangent
+through it, so its (N, S, S) f32 logits are live for one row only.
 """
 from __future__ import annotations
 
@@ -101,5 +107,37 @@ def flash_attention(q, k, v, *, causal: bool = True, window: int = 0,
                         pltpu.VMEM((bq, h), jnp.float32)],
         out_shape=jax.ShapeDtypeStruct((b, n, sq, h), q.dtype),
         interpret=interpret,
+        name="flash_attention",
     )(qt, kt, vt)
     return out.transpose(0, 2, 1, 3)
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5))
+def flash_attention_trainable(q, k, v, causal: bool = True, window: int = 0,
+                              interpret: bool = False):
+    """``flash_attention`` with a gradient (see the module docstring)."""
+    return flash_attention(q, k, v, causal=causal, window=window,
+                           interpret=interpret)
+
+
+def _trainable_fwd(q, k, v, causal, window, interpret):
+    out = flash_attention(q, k, v, causal=causal, window=window,
+                          interpret=interpret)
+    return out, (q, k, v)
+
+
+def _trainable_bwd(causal, window, interpret, res, do):
+    from repro.kernels.ref import flash_attention_ref
+
+    def row(args):
+        qb, kb, vb, dob = args
+        _, vjp = jax.vjp(
+            lambda a, b_, c: flash_attention_ref(
+                a[None], b_[None], c[None], causal=causal,
+                window=window)[0], qb, kb, vb)
+        return vjp(dob)
+
+    return jax.lax.map(row, res + (do,))
+
+
+flash_attention_trainable.defvjp(_trainable_fwd, _trainable_bwd)

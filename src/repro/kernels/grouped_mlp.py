@@ -22,8 +22,11 @@ Validity comes in two interchangeable forms:
   disappears entirely: it becomes *metadata*.  A per-tile valid-row count
   (``tile_n``, shape ``(K, T/BT)``) rides the scalar-prefetch operand and
   drives ``pl.when`` tile skipping; a per-row mask rides a tiny
-  ``(K, T)`` int32 input.  All loads/stores stay block-aligned (a
-  ``BlockSpec`` index map addresses whole tiles, so an exact row gather
+  ``(K, T, 1)`` int32 input (a ``(1, BT, 1)`` block: the TPU lowering
+  needs the last two block dims to be multiples of (8, 128) or the whole
+  array dim, which a ``(1, BT)`` block over ``(K, T)`` is not).  All
+  loads/stores stay block-aligned (a ``BlockSpec`` index map addresses
+  whole tiles, so an exact row gather
   cannot be expressed there — tile-granular skipping plus in-tile masking
   is the lowering-friendly equivalent and costs at most one partial tile
   per source segment).
@@ -124,7 +127,7 @@ def _fwd_kernel(tn_ref, x_ref, mask_ref, wi_ref, wg_ref, wo_ref, *rest,
 
     @pl.when(n > 0)                       # skip tiles with no valid row
     def _compute():
-        m = mask_ref[0][:, None] > 0                  # (BT, 1)
+        m = mask_ref[0] > 0                           # (BT, 1)
         x = jnp.where(m, x_ref[0], 0)                 # (BT, D)
         h1 = jnp.dot(x, wi_ref[0], preferred_element_type=jnp.float32)
         if has_gate:
@@ -141,7 +144,7 @@ def _fwd_kernel(tn_ref, x_ref, mask_ref, wi_ref, wg_ref, wo_ref, *rest,
 
     @pl.when(f == nf - 1)
     def _write():
-        m = mask_ref[0][:, None] > 0
+        m = mask_ref[0] > 0
         y_ref[0] = jnp.where(m, acc_ref[...], 0.0).astype(y_ref.dtype)
         # (n == 0 tiles write zeros: acc was only ever initialized)
 
@@ -192,7 +195,7 @@ def _forward(x, wi, wg, wo, mask, *, act: str, interpret: bool,
             grid=grid,
             in_specs=[
                 pl.BlockSpec((1, bt, d), lambda k, t, f, tn: (k, t, 0)),
-                pl.BlockSpec((1, bt), lambda k, t, f, tn: (k, t)),
+                pl.BlockSpec((1, bt, 1), lambda k, t, f, tn: (k, t, 0)),
                 pl.BlockSpec((1, d, bf), lambda k, t, f, tn: (k, 0, f)),
                 pl.BlockSpec((1, d, bf), lambda k, t, f, tn: (k, 0, f)),
                 pl.BlockSpec((1, bf, d), lambda k, t, f, tn: (k, f, 0)),
@@ -202,7 +205,8 @@ def _forward(x, wi, wg, wo, mask, *, act: str, interpret: bool,
         ),
         out_shape=tuple(out_shape),
         interpret=interpret,
-    )(tile_n, x, mask, wi, wg, wo)
+        name="grouped_mlp_fwd",
+    )(tile_n, x, mask[..., None], wi, wg, wo)
     y = out[0][:, :t_] if tp != t_ else out[0]
     if not save_residuals:
         return y
@@ -230,7 +234,7 @@ def _dgrad_kernel(tn_ref, dy_ref, mask_ref, h1_ref, h2_ref, wi_ref, wg_ref,
 
     @pl.when(n > 0)
     def _compute():
-        m = mask_ref[0][:, None] > 0
+        m = mask_ref[0] > 0
         g = jnp.where(m, dy_ref[0], 0).astype(jnp.float32)    # (BT, D)
         # dh = g @ wo^T : contract the model dim of both operands
         dh = jax.lax.dot_general(
@@ -273,7 +277,7 @@ def _dgrad_kernel(tn_ref, dy_ref, mask_ref, h1_ref, h2_ref, wi_ref, wg_ref,
 
     @pl.when(f == nf - 1)
     def _write():
-        m = mask_ref[0][:, None] > 0
+        m = mask_ref[0] > 0
         dx_ref[0] = jnp.where(m, acc_ref[...], 0.0).astype(dx_ref.dtype)
 
 
@@ -303,7 +307,7 @@ def _dgrad(dy, mask, h1, h2, wi, wg, wo, tile_n, *, act: str,
             grid=grid,
             in_specs=[
                 pl.BlockSpec((1, bt, d), lambda k, t, f, tn: (k, t, 0)),
-                pl.BlockSpec((1, bt), lambda k, t, f, tn: (k, t)),
+                pl.BlockSpec((1, bt, 1), lambda k, t, f, tn: (k, t, 0)),
                 res_spec,                                       # h1
                 res_spec,                                       # h2
                 pl.BlockSpec((1, d, bf), lambda k, t, f, tn: (k, 0, f)),
@@ -315,7 +319,8 @@ def _dgrad(dy, mask, h1, h2, wi, wg, wo, tile_n, *, act: str,
         ),
         out_shape=tuple(out_shape),
         interpret=interpret,
-    )(tile_n, dy, mask, h1, h2, wi, wg, wo)
+        name="grouped_mlp_dgrad",
+    )(tile_n, dy, mask[..., None], h1, h2, wi, wg, wo)
 
 
 # ---------------------------------------------------------------------------
@@ -341,7 +346,7 @@ def _wgrad_kernel(tn_ref, x_ref, dy_ref, mask_ref, dh1_ref, dh2_ref, h_ref,
 
     @pl.when(n > 0)                       # reduce only valid token tiles
     def _accum():
-        m = mask_ref[0][:, None] > 0
+        m = mask_ref[0] > 0
         xm = jnp.where(m, x_ref[0], 0)                        # (BT, BD)
         g = jnp.where(m, dy_ref[0], 0)                        # (BT, BD)
         cn = (((0,), (0,)), ((), ()))     # contract the token dim
@@ -399,7 +404,7 @@ def _wgrad(x, dy, mask, dh1, dh2, h, tile_n, wdtype, *, interpret: bool,
             in_specs=[
                 pl.BlockSpec((1, bt, bd), lambda k, d, f, t, tn: (k, t, d)),
                 pl.BlockSpec((1, bt, bd), lambda k, d, f, t, tn: (k, t, d)),
-                pl.BlockSpec((1, bt), lambda k, d, f, t, tn: (k, t)),
+                pl.BlockSpec((1, bt, 1), lambda k, d, f, t, tn: (k, t, 0)),
                 res_spec,                                       # dh1
                 res_spec,                                       # dh2
                 res_spec,                                       # h
@@ -409,7 +414,8 @@ def _wgrad(x, dy, mask, dh1, dh2, h, tile_n, wdtype, *, interpret: bool,
         ),
         out_shape=tuple(out_shape),
         interpret=interpret,
-    )(tile_n, x, dy, mask, dh1, dh2, h)
+        name="grouped_mlp_wgrad",
+    )(tile_n, x, dy, mask[..., None], dh1, dh2, h)
     if has_gate:
         return out[0], out[1], out[2]
     return out[0], None, out[1]

@@ -1,8 +1,9 @@
 """Jitted public wrappers around the Pallas kernels.
 
-On this CPU container the kernels execute in ``interpret=True`` mode (the
+On the CPU backend the kernels execute in ``interpret=True`` mode (the
 kernel body runs as traced Python — numerically identical to the TPU
-lowering).  On a real TPU backend ``interpret`` switches off automatically.
+lowering); on a TPU they compile to Mosaic.  Any other backend is an
+error: there is no silent fallback that would hide which device ran.
 """
 from __future__ import annotations
 
@@ -16,8 +17,30 @@ from repro.kernels import grouped_mlp as _gm
 from repro.kernels import paged_attention as _pa
 
 
+# the ``name=`` of every pallas_call; a TPU compile names the kernel's
+# custom call after it, so these find a kernel in compiled HLO text
+KERNEL_NAMES = ("grouped_mlp_fwd", "grouped_mlp_dgrad", "grouped_mlp_wgrad",
+                "flash_attention", "paged_decode_attention")
+
+
+def compiled_kernels(hlo_text: str) -> frozenset:
+    """Names (from ``KERNEL_NAMES``) of the Pallas kernels that a compiled
+    program's text carries as ``tpu_custom_call`` ops.  Interpret-mode
+    (CPU) programs carry none."""
+    found = set()
+    for line in hlo_text.splitlines():
+        if 'custom_call_target="tpu_custom_call"' in line:
+            found.update(n for n in KERNEL_NAMES if n in line)
+    return frozenset(found)
+
+
 def _interpret() -> bool:
-    return jax.default_backend() != "tpu"
+    backend = jax.default_backend()
+    if backend not in ("cpu", "tpu"):
+        raise NotImplementedError(
+            f"Pallas kernels run on TPU (compiled) or CPU (interpret mode); "
+            f"backend {backend!r} is neither")
+    return backend == "cpu"
 
 
 @partial(jax.jit, static_argnames=("act",))
@@ -40,7 +63,9 @@ def grouped_mlp(x, wi, wg, wo, group_sizes=None, row_valid=None, *,
 
 @partial(jax.jit, static_argnames=("causal", "window"))
 def flash_attention(q, k, v, *, causal: bool = True, window: int = 0):
-    """Flash attention, q/k/v (B,S,N,H).
+    """Flash attention, q/k/v (B,S,N,H).  Differentiable: the Pallas
+    kernel runs the forward, the backward recomputes in XLA (see
+    ``repro.kernels.flash_attention``).
 
     The PREFILL kernel still tiles over ``nq`` equal heads, so GQA K/V are
     expanded here — prefill-only cost, paid once per sequence.  The decode
@@ -53,8 +78,8 @@ def flash_attention(q, k, v, *, causal: bool = True, window: int = 0):
         rep = nq // nkv
         k = jnp.repeat(k, rep, axis=2)
         v = jnp.repeat(v, rep, axis=2)
-    return _fa.flash_attention(q, k, v, causal=causal, window=window,
-                               interpret=_interpret())
+    return _fa.flash_attention_trainable(q, k, v, causal, window,
+                                         _interpret())
 
 
 @partial(jax.jit, static_argnames=("page_size", "window", "softcap"))
@@ -63,7 +88,7 @@ def paged_decode_attention(q, k_pool, v_pool, row_idx, positions, *,
                            softcap: float = 0.0):
     """Block-paged decode attention over the flat KV pool.
 
-    q: (B, nq, hd); k/v_pool: (num_rows, nkv, hd); row_idx: (B, max_kv)
+    q: (B, nq, hd); k/v_pool: (nkv, num_rows, hd); row_idx: (B, max_kv)
     int32 per-token pool rows (page-aligned — the kernel consumes the
     page-granular table ``row_idx[:, ::page_size] // page_size``);
     positions: (B,) int32 write positions.  Native GQA: the kernel reads

@@ -1,10 +1,11 @@
 """Pallas TPU kernel: block-paged decode attention (PagedAttention-style).
 
 One decode token per sequence against the flat block-paged KV pool
-(``repro.models.attention.init_paged_kv_cache``: ``(num_rows, nkv, hd)``
-token rows, no batch dimension).  The pre-kernel path gathered every
-sequence's rows into a ``(B, max_kv, nkv, hd)`` copy per sublayer per
-step (``k[row_idx]``) and blew GQA K/V up to ``nq`` heads — this kernel
+(``repro.models.attention.init_paged_kv_cache``: head-major
+``(nkv, num_rows, hd)`` token rows, no batch dimension).  The pre-kernel
+path gathered every sequence's rows into a ``(B, max_kv, nkv, hd)`` copy
+per sublayer per step (``k[row_idx]``) and blew GQA K/V up to ``nq``
+heads — this kernel
 reads the pool IN PLACE through the page table and consumes the ``nkv``
 KV heads natively.
 
@@ -14,10 +15,13 @@ Grid is ``(B, nkv, max_kv / page_size)`` with the KV-page axis innermost.
 The page table arrives as a scalar-prefetch operand
 (``pltpu.PrefetchScalarGridSpec``): ``block_tbl[b, i]`` is the POOL PAGE
 holding sequence ``b``'s tokens ``[i*page_size, (i+1)*page_size)``, so
-the K/V BlockSpec index map is ``(block_tbl[b, i], head, 0)`` — the pool
+the K/V BlockSpec index map is ``(head, block_tbl[b, i], 0)`` — the pool
 row axis is blocked at page granularity and each program DMAs exactly
-one page of one KV head from the flat pool.  No per-sequence KV copy is
-ever materialized; unallocated tail pages point at the reserved trash
+one page of one KV head from the flat pool.  The pool is head-major so
+that the block's last two dims are ``(page_size, hd)``: the TPU lowering
+requires them to be multiples of (8, 128) or whole array dims, which a
+``(page_size, 1, hd)`` block over a row-major ``(num_rows, nkv, hd)``
+pool is not.  No per-sequence KV copy is ever materialized; unallocated tail pages point at the reserved trash
 page 0 and are skipped by the position mask below.  Q is reshaped to
 ``(B, nkv, group, hd)`` so a program's ``group = nq // nkv`` query heads
 share its KV head (native GQA — no ``jnp.repeat`` expansion anywhere).
@@ -39,7 +43,7 @@ absolute in f32, bf16 inputs accumulate in f32.
 
 Interpret mode
 --------------
-On non-TPU backends ``repro.kernels.ops._interpret()`` switches
+On the CPU backend ``repro.kernels.ops._interpret()`` switches
 ``interpret=True`` and the kernel body runs as traced Python — bitwise
 the math above, minus the DMA pipeline.  The pure-XLA gather fallback
 stays available behind ``ModelConfig.paged_attn_kernel = False``.
@@ -77,7 +81,7 @@ def _kernel(tbl_ref, pos_ref, q_ref, k_ref, v_ref, o_ref, m_ref, l_ref,
     @pl.when(run)
     def _compute():
         q = q_ref[0, 0].astype(jnp.float32)             # (G, H)
-        k = k_ref[:, 0].astype(jnp.float32)             # (BK, H)
+        k = k_ref[0].astype(jnp.float32)                # (BK, H)
         s = jnp.dot(q, k.T, preferred_element_type=jnp.float32) * scale
         if softcap > 0.0:
             s = jnp.tanh(s / softcap) * softcap
@@ -93,7 +97,7 @@ def _kernel(tbl_ref, pos_ref, q_ref, k_ref, v_ref, o_ref, m_ref, l_ref,
         alpha = jnp.exp(m_prev - m_new)
         l_ref[...] = l_ref[...] * alpha + p.sum(axis=1, keepdims=True)
         acc_ref[...] = acc_ref[...] * alpha + jnp.dot(
-            p, v_ref[:, 0].astype(jnp.float32),
+            p, v_ref[0].astype(jnp.float32),
             preferred_element_type=jnp.float32)
         m_ref[...] = m_new
 
@@ -106,12 +110,12 @@ def _kernel(tbl_ref, pos_ref, q_ref, k_ref, v_ref, o_ref, m_ref, l_ref,
 def paged_decode_attention(q, k_pool, v_pool, block_tbl, positions, *,
                            page_size: int, window: int = 0,
                            softcap: float = 0.0, interpret: bool = False):
-    """q: (B, nq, hd); k/v_pool: (num_rows, nkv, hd) flat page pool;
+    """q: (B, nq, hd); k/v_pool: (nkv, num_rows, hd) flat page pool;
     block_tbl: (B, max_kv/page_size) int32 pool-page ids; positions: (B,)
     int32 per-sequence write positions.  Returns (B, nq, hd) in q.dtype
     with f32 accumulation.  See the module docstring for the contract."""
     b, nq, h = q.shape
-    num_rows, nkv, _ = k_pool.shape
+    nkv, num_rows, _ = k_pool.shape
     assert nq % nkv == 0, (nq, nkv)
     assert num_rows % page_size == 0, (num_rows, page_size)
     group = nq // nkv
@@ -126,10 +130,10 @@ def paged_decode_attention(q, k_pool, v_pool, block_tbl, positions, *,
         in_specs=[
             pl.BlockSpec((1, 1, group, h),
                          lambda b_, n_, i_, tbl, pos: (b_, n_, 0, 0)),
-            pl.BlockSpec((page_size, 1, h),
-                         lambda b_, n_, i_, tbl, pos: (tbl[b_, i_], n_, 0)),
-            pl.BlockSpec((page_size, 1, h),
-                         lambda b_, n_, i_, tbl, pos: (tbl[b_, i_], n_, 0)),
+            pl.BlockSpec((1, page_size, h),
+                         lambda b_, n_, i_, tbl, pos: (n_, tbl[b_, i_], 0)),
+            pl.BlockSpec((1, page_size, h),
+                         lambda b_, n_, i_, tbl, pos: (n_, tbl[b_, i_], 0)),
         ],
         out_specs=pl.BlockSpec((1, 1, group, h),
                                lambda b_, n_, i_, tbl, pos: (b_, n_, 0, 0)),
@@ -141,6 +145,7 @@ def paged_decode_attention(q, k_pool, v_pool, block_tbl, positions, *,
         kern, grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((b, nkv, group, h), q.dtype),
         interpret=interpret,
+        name="paged_decode_attention",
     )(block_tbl.astype(jnp.int32), positions.astype(jnp.int32),
       qg, k_pool, v_pool)
     return out.reshape(b, nq, h)

@@ -42,23 +42,23 @@ def grouped_mlp_ref(x, wi, wg, wo, act: str = "silu_glu",
 
 def paged_decode_attention_ref(q, k_pool, v_pool, row_idx, positions, *,
                                window: int = 0, softcap: float = 0.0):
-    """q: (B, nq, hd); k/v_pool: (num_rows, nkv, hd); row_idx: (B, max_kv)
+    """q: (B, nq, hd); k/v_pool: (nkv, num_rows, hd); row_idx: (B, max_kv)
     int32 pool rows; positions: (B,) int32 write positions.
 
     The pre-kernel XLA path, kept as the oracle: gather every sequence's
-    rows into a (B, max_kv, nkv, hd) view, mask ``t <= positions[b]``
+    rows into a (nkv, B, max_kv, hd) view, mask ``t <= positions[b]``
     (windowed, soft-capped), softmax in f32.  Masked tokens — including
     every trash-page row past a sequence's allocation — get EXACTLY zero
     probability (exp(-1e30 - m) underflows to 0.0), so the unallocated
     tail contributes no mass here or in the kernel.
     """
-    kb = k_pool[row_idx].astype(jnp.float32)        # (B, max_kv, nkv, hd)
-    vb = v_pool[row_idx].astype(jnp.float32)
+    kb = k_pool[:, row_idx].astype(jnp.float32)     # (nkv, B, max_kv, hd)
+    vb = v_pool[:, row_idx].astype(jnp.float32)
     b, nq, h = q.shape
-    nkv = k_pool.shape[1]
+    nkv = k_pool.shape[0]
     g = nq // nkv
     qg = q.reshape(b, nkv, g, h).astype(jnp.float32)
-    s = jnp.einsum("bkgh,bskh->bkgs", qg, kb) / jnp.sqrt(h)
+    s = jnp.einsum("bkgh,kbsh->bkgs", qg, kb) / jnp.sqrt(h)
     if softcap > 0.0:
         s = jnp.tanh(s / softcap) * softcap
     kpos = jnp.arange(row_idx.shape[1])
@@ -67,7 +67,7 @@ def paged_decode_attention_ref(q, k_pool, v_pool, row_idx, positions, *,
         valid &= kpos[None, :] > positions[:, None] - window
     s = jnp.where(valid[:, None, None, :], s, -1e30)
     p = jax.nn.softmax(s, axis=-1)
-    out = jnp.einsum("bkgs,bskh->bkgh", p, vb)
+    out = jnp.einsum("bkgs,kbsh->bkgh", p, vb)
     return out.reshape(b, nq, h).astype(q.dtype)
 
 

@@ -19,7 +19,7 @@ from repro.core import moe as moe_core
 from repro.core.moe import MoERuntime
 from repro.models import model as mdl
 from repro.optim.adamw import OptState
-from repro.train.step import TrainState
+from repro.train.step import TrainState, state_shardings
 
 
 def ep_size(mesh: Mesh) -> int:
@@ -37,13 +37,12 @@ def mesh_batch_size(mesh: Mesh) -> int:
     return n
 
 
-def make_runtime(cfg: ModelConfig, mesh: Optional[Mesh], *,
+def make_runtime(cfg: ModelConfig, mesh: Mesh, *,
                  impl: str = "ring", use_pallas: bool = False,
                  unroll: bool = False, capacity: int = 0,
                  rules_overrides: Optional[dict] = None) -> mdl.Runtime:
-    if mesh is None:
-        return mdl.Runtime(moe=MoERuntime(mesh=None), use_pallas=use_pallas,
-                           unroll=unroll)
+    """The distributed runtime: the sparse FSSDP MoE layer over the
+    mesh's ``model`` axis (a 1x1 mesh runs it on one device)."""
     rules = shd.resolve_rules(mesh, rules_overrides)
     moe_rt = MoERuntime(
         mesh=mesh, ep_axis="model", batch_axes=batch_axes(mesh),
@@ -58,7 +57,7 @@ def make_runtime(cfg: ModelConfig, mesh: Optional[Mesh], *,
 # Parameters / optimizer / plan tables
 # ---------------------------------------------------------------------------
 def param_shardings(cfg: ModelConfig, mesh: Mesh):
-    return shd.decl_shardings(mdl.param_decls(cfg, ep_size(mesh)), mesh)
+    return state_shardings(cfg, mesh).params
 
 
 def abstract_params(cfg: ModelConfig, mesh: Mesh):

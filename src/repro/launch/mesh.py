@@ -5,10 +5,17 @@ touches jax device state (jax locks the device count on first init).
 """
 from __future__ import annotations
 
-from typing import Optional
+from typing import Sequence
 
-from repro.common.compat import make_mesh
+import jax
+
 from repro.common.config import MeshConfig
+
+
+def make_mesh(shape: Sequence[int], axes: Sequence[str]):
+    """``jax.make_mesh`` with every axis ``Auto`` (GSPMD-partitioned)."""
+    return jax.make_mesh(tuple(shape), tuple(axes),
+                         axis_types=(jax.sharding.AxisType.Auto,) * len(axes))
 
 
 def make_production_mesh(*, multi_pod: bool = False):

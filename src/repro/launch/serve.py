@@ -16,6 +16,10 @@ group, so the bus's same-host dedup applies), an initial publication
 broadcast through the bus, prompts routed to the healthy replicas, and a
 per-replica health report at the end.
 
+The model runs on a one-device ``(data, model)`` mesh through the sparse
+MoE layer, with the Pallas kernels on (compiled on a TPU, interpret mode
+on the CPU).
+
 ``--continuous`` serves through the continuous-batching
 ``repro.serve.scheduler.RequestScheduler`` instead of fixed-batch
 ``Engine.generate``: each prompt keeps its TRUE length (no padding
@@ -48,6 +52,8 @@ def main():
     ap.add_argument("--temperature", type=float, default=0.0)
     ap.add_argument("--seed", type=int, default=0)
     args = ap.parse_args()
+    from repro.common.compile_cache import enable_compile_cache
+    enable_compile_cache()
 
     import jax
     import numpy as np
@@ -55,12 +61,13 @@ def main():
     import repro.configs as configs
     from repro.checkpoint import store
     from repro.core import moe as moe_core
+    from repro.launch import inputs as inp
+    from repro.launch.mesh import make_debug_mesh
     from repro.models import model as mdl
     from repro.serve.engine import Engine
 
     cfg = (configs.get_smoke(args.arch) if args.smoke
            else configs.get(args.arch))
-    rt = mdl.Runtime()
     params = mdl.init_params(cfg, jax.random.PRNGKey(args.seed))
     pa, version = None, 0
     if args.checkpoint_dir:
@@ -90,10 +97,10 @@ def main():
             if serve_state is not None and int(
                     np.max(serve_state["pa"].owner_dev)) > 0:
                 # plan from a multi-device (EP > 1) training run: this
-                # launcher decodes mesh-less, where owner_row is only
-                # meaningful per device — reading it flat would gather
-                # wrong buffer rows.  Fall back to the fresh single-host
-                # plan instead of silently decoding garbage.
+                # launcher serves on a one-device mesh, where owner_row is
+                # only meaningful per device — reading it flat would
+                # gather wrong buffer rows.  Fall back to the fresh
+                # single-device plan instead of silently decoding garbage.
                 print("serving state is from an EP > 1 run; single-host "
                       "decode rebuilds a local plan instead")
                 version = serve_state["version"]
@@ -112,6 +119,11 @@ def main():
         sh = homogeneous_sharding(moe_core.num_moe_layers(cfg),
                                   cfg.moe.num_experts, 1)
         pa = moe_core.plan_to_arrays(ep_materialization(sh))
+    # the runtime's materialization matches the plan's: extra slots mean a
+    # sparse (ring) plan, none an expert-parallel one
+    sparse = pa is not None and pa.extra_experts.shape[-1] > 0
+    rt = inp.make_runtime(cfg, make_debug_mesh(1, 1),
+                          impl="ring" if sparse else "ep", use_pallas=True)
 
     prompts = args.prompt or ["Hello world", "The scheduler said"]
     maxp = max(len(p) for p in prompts)

@@ -1,9 +1,11 @@
 """Training launcher.
 
-Single-host CPU demo runs use a debug mesh (set
-``XLA_FLAGS=--xla_force_host_platform_device_count=8``); on a real TPU pod
-the same script runs under multi-process jax.distributed with the
-production mesh.
+Runs the FSSDP training loop on a ``(data, model)`` mesh built from the
+first ``--mesh-data x --mesh-model`` devices.  The default 1x1 mesh runs
+the sparse MoE layer and the Pallas kernels on one chip; on the CPU the
+kernels run in interpret mode, and a multi-device run simulates its mesh
+on host devices (set ``XLA_FLAGS=--xla_force_host_platform_device_count=N``
+in the environment).
 
   PYTHONPATH=src python -m repro.launch.train --arch gpt-moe-s --smoke \
       --steps 50 --impl ring --mesh-data 2 --mesh-model 4
@@ -12,10 +14,20 @@ from __future__ import annotations
 
 import argparse
 import json
-import os
+from typing import NamedTuple, Optional
 
 
-def main():
+class TrainSetup(NamedTuple):
+    """Everything ``train_loop`` takes besides the config."""
+    mesh: object
+    rt: object
+    tc: object
+    stream: object
+    scheduler: Optional[object]
+    supervisor: Optional[object]
+
+
+def parse_args(argv=None):
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", required=True)
     ap.add_argument("--smoke", action="store_true",
@@ -25,9 +37,9 @@ def main():
     ap.add_argument("--seq-len", type=int, default=128)
     ap.add_argument("--impl", default="ring",
                     choices=["ring", "a2a", "dense", "ep"])
-    ap.add_argument("--mesh-data", type=int, default=0,
-                    help="0 = single device, no mesh")
-    ap.add_argument("--mesh-model", type=int, default=4)
+    ap.add_argument("--mesh-data", type=int, default=1)
+    ap.add_argument("--mesh-model", type=int, default=1,
+                    help="expert-parallel (EP) degree")
     ap.add_argument("--lr", type=float, default=3e-4)
     ap.add_argument("--microbatch", type=int, default=0)
     ap.add_argument("--resharding-interval", type=int, default=100)
@@ -62,31 +74,26 @@ def main():
     ap.add_argument("--skew", type=float, default=0.0)
     ap.add_argument("--seed", type=int, default=0)
     ap.add_argument("--log-json", default="")
-    args = ap.parse_args()
+    args = ap.parse_args(argv)
+    if args.elastic and not args.checkpoint_dir:
+        ap.error("--elastic needs --checkpoint-dir (the shrink path "
+                 "rolls back to the newest intact checkpoint)")
+    return args
 
-    if args.mesh_data:
-        want = args.mesh_data * args.mesh_model
-        os.environ.setdefault(
-            "XLA_FLAGS", f"--xla_force_host_platform_device_count={want}")
 
-    import jax
-    import numpy as np
-
-    import repro.configs as configs
+def build(cfg, args) -> TrainSetup:
+    """The mesh, runtime, data and scheduler ``main`` trains ``cfg`` with
+    (``cfg`` is a parameter so that a caller may cut its depth)."""
     from repro.common.config import TrainConfig
     from repro.core.schedule import ReshardingPolicy
     from repro.data.pipeline import make_stream
     from repro.launch import inputs as inp
     from repro.launch.mesh import make_debug_mesh
-    from repro.train import step as step_lib
-    from repro.train.trainer import HecateScheduler, train_loop
+    from repro.train.trainer import HecateScheduler
 
-    cfg = configs.get_smoke(args.arch) if args.smoke else configs.get(args.arch)
-    mesh = None
-    if args.mesh_data:
-        mesh = make_debug_mesh(args.mesh_data, args.mesh_model)
-    rt = inp.make_runtime(cfg, mesh, impl=args.impl)
-    ep = mesh.shape["model"] if mesh is not None else 1
+    mesh = make_debug_mesh(args.mesh_data, args.mesh_model)
+    rt = inp.make_runtime(cfg, mesh, impl=args.impl, use_pallas=True)
+    ep = mesh.shape["model"]
 
     tc = TrainConfig(learning_rate=args.lr, total_steps=args.steps,
                      warmup_steps=max(args.steps // 10, 1), seed=args.seed,
@@ -107,32 +114,38 @@ def main():
 
     supervisor = None
     if args.elastic:
-        if not args.checkpoint_dir:
-            ap.error("--elastic needs --checkpoint-dir (the shrink path "
-                     "rolls back to the newest intact checkpoint)")
         from repro.train.supervisor import TrainSupervisor, surviving_mesh
-        dp = max(args.mesh_data, 1)
 
         def runtime_factory(ep_new):
-            if mesh is None:
-                return rt               # mesh-less run: nothing to shrink
-            return inp.make_runtime(cfg, surviving_mesh(dp, ep_new),
-                                    impl=args.impl)
+            return inp.make_runtime(cfg, surviving_mesh(args.mesh_data,
+                                                        ep_new),
+                                    impl=args.impl, use_pallas=True)
 
         supervisor = TrainSupervisor(ep=ep,
                                      runtime_factory=runtime_factory,
                                      min_ep=args.min_ep,
                                      step_timeout_s=args.step_timeout)
+    return TrainSetup(mesh, rt, tc, stream, scheduler, supervisor)
 
+
+def main(argv=None):
+    args = parse_args(argv)
+    from repro.common.compile_cache import enable_compile_cache
+    enable_compile_cache()
+
+    import repro.configs as configs
+    from repro.train.trainer import save_train_state, train_loop
+
+    cfg = configs.get_smoke(args.arch) if args.smoke else configs.get(args.arch)
+    s = build(cfg, args)
     # periodic checkpointing + auto-resume now live INSIDE train_loop
     # (crash-safe: atomic renames, per-array checksums, keep-last GC,
     # resume from the newest intact step — see repro.train.trainer)
-    state, history = train_loop(cfg, rt, tc, stream, scheduler=scheduler,
-                                num_steps=args.steps,
-                                supervisor=supervisor)
+    state, history = train_loop(cfg, s.rt, s.tc, s.stream,
+                                scheduler=s.scheduler, num_steps=args.steps,
+                                supervisor=s.supervisor)
     if args.checkpoint_dir:
-        from repro.train.trainer import save_train_state
-        save_train_state(tc, int(state.step), state, scheduler)
+        save_train_state(s.tc, int(state.step), state, s.scheduler)
     if args.log_json:
         with open(args.log_json, "w") as f:
             json.dump(history, f)

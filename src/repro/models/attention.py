@@ -6,11 +6,14 @@ in for train/prefill when ``use_pallas=True``.
 """
 from __future__ import annotations
 
+from functools import partial
 from typing import Optional
 
 import jax
 import jax.numpy as jnp
+from jax.sharding import PartitionSpec as P
 
+from repro.common import sharding as shd
 from repro.common.config import ModelConfig
 from repro.common.params import Param
 from repro.models.layers import apply_rope, default_mrope_sections
@@ -79,11 +82,32 @@ def make_mask(sq: int, skv: int, *, causal: bool, window: int = 0,
     return m[None, None]
 
 
+def _flash(q, k, v, *, causal: bool, window: int, mesh=None, rules=None):
+    """The Pallas flash kernel.  GSPMD cannot partition a Mosaic kernel,
+    so on a mesh it runs per shard under ``shard_map``: batch over the
+    batch axes, heads over the model axis when both the query and the KV
+    head counts divide it (else every shard holds all heads)."""
+    from repro.kernels import ops as kops
+    fn = partial(kops.flash_attention, causal=causal, window=window)
+    if mesh is None:
+        return fn(q, k, v)
+    qs = shd.shape_aware_pspec(q.shape, ("batch", None, "heads", None),
+                               rules, mesh)
+    ks = shd.shape_aware_pspec(k.shape, ("batch", None, "kv_heads", None),
+                               rules, mesh)
+    if qs[2] != ks[2]:
+        qs = ks = P(qs[0], None, None, None)
+    return jax.shard_map(fn, mesh=mesh, in_specs=(qs, ks, ks),
+                         out_specs=qs, check_vma=False)(q, k, v)
+
+
 def attention(p, cfg: ModelConfig, x, positions, *, kind: str = "attn",
               causal: bool = True, xa=None, use_pallas: bool = False,
-              return_kv: bool = False):
+              return_kv: bool = False, mesh=None, rules=None):
     """Full-sequence attention (train / prefill). Returns (B,S,D)
-    (and the rotated (k, v) when ``return_kv`` — prefill cache fill)."""
+    (and the rotated (k, v) when ``return_kv`` — prefill cache fill).
+    ``mesh``/``rules``: the model's mesh and logical-axis rules, which
+    place the Pallas kernel's shards (``use_pallas``)."""
     q, k, v = _project_qkv(p, x, xa=xa)
     mr = default_mrope_sections(cfg.head_dim) if cfg.mrope else None
     if xa is None:
@@ -95,8 +119,8 @@ def attention(p, cfg: ModelConfig, x, positions, *, kind: str = "attn",
         mask = make_mask(q.shape[1], k.shape[1], causal=causal, window=window)
         mask = jnp.broadcast_to(mask, (q.shape[0], 1, q.shape[1], k.shape[1]))
     if use_pallas and mask is not None and xa is None and cfg.attn_logit_softcap == 0.0:
-        from repro.kernels import ops as kops
-        out = kops.flash_attention(q, k, v, causal=causal, window=window)
+        out = _flash(q, k, v, causal=causal, window=window, mesh=mesh,
+                     rules=rules)
     else:
         out = _sdpa(q, k, v, mask, cfg.attn_logit_softcap, cfg.head_dim)
     out = jnp.einsum("bsnh,nhd->bsd", out, p["wo"].astype(x.dtype))
@@ -136,11 +160,13 @@ def init_paged_kv_cache(cfg: ModelConfig, num_rows: int, dtype):
     ``num_rows = num_pages * page_size`` token rows shared by every
     sequence.  Which rows belong to which sequence is pure metadata (the
     scheduler's page tables — see ``repro.serve.kv_pool``); the device
-    arrays carry no batch dimension at all."""
+    arrays carry no batch dimension at all.  The pool is head-major,
+    ``(nkv, num_rows, hd)``, so one page of one KV head is a contiguous
+    ``(page_size, hd)`` tile — the block the paged kernel DMAs."""
     nkv, hd = cfg.num_kv_heads, cfg.head_dim
     return {
-        "k": jnp.zeros((num_rows, nkv, hd), dtype),
-        "v": jnp.zeros((num_rows, nkv, hd), dtype),
+        "k": jnp.zeros((nkv, num_rows, hd), dtype),
+        "v": jnp.zeros((nkv, num_rows, hd), dtype),
     }
 
 
@@ -164,7 +190,7 @@ def decode_attention_paged(p, cfg: ModelConfig, x, cache, positions,
     no ``(B, max_kv, nkv, hd)`` gather copy, native GQA, online softmax
     in f32 (paged-vs-dense parity ≤1e-6 in f32; reduction order is the
     only difference).  Without ``page_size`` (or with the config flag
-    off) the pure-XLA fallback gathers ``k[row_idx]`` and reuses
+    off) the pure-XLA fallback gathers ``k[:, row_idx]`` and reuses
     ``_sdpa`` — identical math to the dense path, BIT-exact with a
     dense-cache trace of the same sequence.  Both laws are asserted in
     tests/test_serve_batching.py.  Returns (out, new_cache).
@@ -180,8 +206,8 @@ def decode_attention_paged(p, cfg: ModelConfig, x, cache, positions,
                                      axis=1)[:, 0]  # (B,)
     # slots parked on the trash page collide at row 0 — harmless, nothing
     # live ever reads it; live sequences own disjoint rows by construction
-    k = cache["k"].at[write_rows].set(k_new[:, 0])
-    v = cache["v"].at[write_rows].set(v_new[:, 0])
+    k = cache["k"].at[:, write_rows].set(k_new[:, 0].swapaxes(0, 1))
+    v = cache["v"].at[:, write_rows].set(v_new[:, 0].swapaxes(0, 1))
     window = cfg.sliding_window if kind == "local" else 0
     if page_size is not None and cfg.paged_attn_kernel:
         from repro.kernels import ops as kops
@@ -189,7 +215,9 @@ def decode_attention_paged(p, cfg: ModelConfig, x, cache, positions,
             q[:, 0], k, v, row_idx, positions, page_size=page_size,
             window=window, softcap=cfg.attn_logit_softcap)[:, None]
     else:
-        kb, vb = k[row_idx], v[row_idx]             # (B, max_kv, nkv, hd)
+        # (nkv, B, max_kv, hd) -> (B, max_kv, nkv, hd)
+        kb = k[:, row_idx].transpose(1, 2, 0, 3)
+        vb = v[:, row_idx].transpose(1, 2, 0, 3)
         kpos = jnp.arange(row_idx.shape[1])
         valid = kpos[None, :] <= positions[:, None]
         if window > 0:

@@ -267,7 +267,8 @@ def _superblock(cfg: ModelConfig, rt: Runtime, params_sb, x, positions,
             else:
                 y = attn.attention(p_["attn"], cfg, h, positions, kind=kind,
                                    causal=causal, use_pallas=rt.use_pallas,
-                                   return_kv=collect_cache)
+                                   return_kv=collect_cache, mesh=rt.mesh,
+                                   rules=rt.rules)
                 if collect_cache:
                     y, c = y
                 x2 = x_ + y

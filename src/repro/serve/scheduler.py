@@ -429,8 +429,11 @@ class RequestScheduler:
                     k: dst[k].at[:, slot].set(src[k][:, 0])
                     for k in dst}
             else:
+                # prefill K/V (n_sb, 1, S, nkv, hd) -> the head-major
+                # pool (n_sb, nkv, num_rows, hd)
                 self.cache[f"l{j}"] = {
-                    k: dst[k].at[:, rows].set(src[k][:, 0, :p_len])
+                    k: dst[k].at[:, :, rows].set(
+                        src[k][:, 0, :p_len].swapaxes(1, 2))
                     for k in ("k", "v")}
 
     # ---- decode ---------------------------------------------------------

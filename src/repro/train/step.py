@@ -31,7 +31,9 @@ from typing import Any, Dict, NamedTuple, Optional
 
 import jax
 import jax.numpy as jnp
+from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
+from repro.common import sharding as shd
 from repro.common.config import ModelConfig, TrainConfig
 from repro.common.faults import GRAD_SCALE_KEY
 from repro.core import moe as moe_core
@@ -46,10 +48,26 @@ class TrainState(NamedTuple):
     step: jnp.ndarray
 
 
-def init_state(cfg: ModelConfig, key, ep: int = 1) -> TrainState:
-    params = mdl.init_params(cfg, key, ep)
-    return TrainState(params=params, opt=adamw.init(params),
-                      step=jnp.zeros((), jnp.int32))
+def init_state(cfg: ModelConfig, key, ep: int = 1,
+               mesh: Optional[Mesh] = None) -> TrainState:
+    """Fresh params + AdamW state.  With a mesh the state is created
+    directly in its sharded layout (no device ever holds all of it)."""
+    def make(k):
+        params = mdl.init_params(cfg, k, ep)
+        return TrainState(params=params, opt=adamw.init(params),
+                          step=jnp.zeros((), jnp.int32))
+    if mesh is None:
+        return make(key)
+    return jax.jit(make, out_shardings=state_shardings(cfg, mesh))(key)
+
+
+def state_shardings(cfg: ModelConfig, mesh: Mesh) -> TrainState:
+    """NamedShardings of the train state: params (and both AdamW
+    moments) per their logical axes, counters replicated."""
+    ps = shd.decl_shardings(mdl.param_decls(cfg, mesh.shape["model"]), mesh)
+    rep = NamedSharding(mesh, P())
+    return TrainState(params=ps, opt=adamw.OptState(mu=ps, nu=ps, count=rep),
+                      step=rep)
 
 
 def cross_entropy(logits, labels, ignore: int = -1):

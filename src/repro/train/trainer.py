@@ -587,6 +587,18 @@ def resume_train_state(cfg: ModelConfig, tc: TrainConfig,
     return state, int(state.step)
 
 
+def jit_train_step(cfg: ModelConfig, rt, tc: TrainConfig):
+    """The jitted train step ``train_loop`` runs.  On a mesh the new state
+    keeps the layout of ``step_lib.state_shardings``, so every step sees
+    the input shardings the first one was compiled for."""
+    fn = step_lib.build_train_step(cfg, rt, tc)
+    mesh = getattr(rt, "mesh", None)
+    if mesh is None:
+        return jax.jit(fn)
+    return jax.jit(fn, out_shardings=(step_lib.state_shardings(cfg, mesh),
+                                      None))
+
+
 def train_loop(cfg: ModelConfig, rt, tc: TrainConfig,
                stream: Iterable[Dict[str, np.ndarray]],
                *, scheduler: Optional[HecateScheduler] = None,
@@ -684,9 +696,10 @@ def train_loop(cfg: ModelConfig, rt, tc: TrainConfig,
             counters.resumes += 1
     if state is None:
         state = step_lib.init_state(cfg, jax.random.PRNGKey(tc.seed),
-                                    scheduler.ep if scheduler else 1)
+                                    scheduler.ep if scheduler else 1,
+                                    mesh=getattr(rt, "mesh", None))
     if train_step_fn is None:
-        train_step_fn = jax.jit(step_lib.build_train_step(cfg, rt, tc))
+        train_step_fn = jit_train_step(cfg, rt, tc)
     history = []
     it = iter(stream)
     for _ in range(start):          # align data order with the killed run
@@ -834,8 +847,7 @@ def train_loop(cfg: ModelConfig, rt, tc: TrainConfig,
                                   if h["step"] < i_resume]
                     state = rolled
                     rt = rt_new
-                    train_step_fn = jax.jit(
-                        step_lib.build_train_step(cfg, rt, tc))
+                    train_step_fn = jit_train_step(cfg, rt, tc)
                     counters.elastic_shrinks += 1
                     supervisor.on_shrunk(new_ep,
                                          steps_lost=i - i_resume + 1)
@@ -924,8 +936,7 @@ def train_loop(cfg: ModelConfig, rt, tc: TrainConfig,
                                 f"expected {gstep}")
                         state = regrown
                         rt = rt_new
-                        train_step_fn = jax.jit(
-                            step_lib.build_train_step(cfg, rt, tc))
+                        train_step_fn = jit_train_step(cfg, rt, tc)
                         counters.grow_backs += 1
                         supervisor.on_grow_back()
                         pending_replan = True

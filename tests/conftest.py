@@ -2,7 +2,8 @@
 
 NOTE: no XLA_FLAGS here on purpose — smoke tests and benches must see ONE
 device.  Distributed tests spawn subprocesses that set
---xla_force_host_platform_device_count themselves (see tests/dist_util.py).
+--xla_force_host_platform_device_count themselves (``run_distributed``),
+pinned to the CPU so that a child never reaches for an accelerator.
 """
 import os
 import subprocess
@@ -10,22 +11,13 @@ import sys
 
 import pytest
 
-# The distributed snippets are written against the newer mesh API
-# (jax.make_mesh(..., axis_types=(jax.sharding.AxisType.Auto, ...))).  On
-# JAX versions without AxisType this prelude installs a tolerant shim; on
-# newer JAX it is a no-op (see repro.common.compat).
-_COMPAT_PRELUDE = (
-    "from repro.common.compat import install_axis_type_shim\n"
-    "install_axis_type_shim()\n"
-)
-
-
 def run_distributed(script: str, n_devices: int = 8, timeout: int = 560):
     """Run a python snippet in a subprocess with n host devices."""
     env = dict(os.environ)
+    env["JAX_PLATFORMS"] = "cpu"        # simulated mesh: host devices only
     env["XLA_FLAGS"] = f"--xla_force_host_platform_device_count={n_devices}"
     env["PYTHONPATH"] = os.path.join(os.path.dirname(__file__), "..", "src")
-    r = subprocess.run([sys.executable, "-c", _COMPAT_PRELUDE + script],
+    r = subprocess.run([sys.executable, "-c", script],
                        env=env, capture_output=True, text=True,
                        timeout=timeout)
     if r.returncode != 0:

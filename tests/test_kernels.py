@@ -251,10 +251,11 @@ def _paged_case(seed, positions, nkv, group, h=32, num_pages=24,
     rng = np.random.default_rng(seed)
     b, nq = len(positions), nkv * group
     q = jnp.asarray(rng.standard_normal((b, nq, h)) * 0.4, dtype)
+    # head-major pool (nkv, num_rows, h)
     k = jnp.asarray(rng.standard_normal((num_pages * PS, nkv, h)) * 0.4,
-                    dtype)
+                    dtype).swapaxes(0, 1)
     v = jnp.asarray(rng.standard_normal((num_pages * PS, nkv, h)) * 0.6,
-                    dtype)
+                    dtype).swapaxes(0, 1)
     row_idx = _paged_tables(rng, positions, num_pages)
     return q, k, v, row_idx, jnp.asarray(positions, jnp.int32)
 
@@ -302,8 +303,8 @@ def test_paged_decode_trash_page_never_contributes():
     q, k, v, row_idx, pos = _paged_case(9, [5, 0, 13], nkv=2, group=2)
     out_clean = ops.paged_decode_attention(q, k, v, row_idx, pos,
                                            page_size=PS)
-    kp = k.at[:PS].set(1e4)
-    vp = v.at[:PS].set(1e4)
+    kp = k.at[:, :PS].set(1e4)
+    vp = v.at[:, :PS].set(1e4)
     out_poison = ops.paged_decode_attention(q, kp, vp, row_idx, pos,
                                             page_size=PS)
     np.testing.assert_array_equal(np.asarray(out_clean),
@@ -320,8 +321,10 @@ def test_paged_decode_fully_parked_sequence_matches_oracle():
     relies on idle slots being harmless, not skipped."""
     rng = np.random.default_rng(17)
     q = jnp.asarray(rng.standard_normal((2, 4, 32)) * 0.4, jnp.float32)
-    k = jnp.asarray(rng.standard_normal((5 * PS, 2, 32)), jnp.float32)
-    v = jnp.asarray(rng.standard_normal((5 * PS, 2, 32)), jnp.float32)
+    k = jnp.asarray(rng.standard_normal((5 * PS, 2, 32)),
+                    jnp.float32).swapaxes(0, 1)
+    v = jnp.asarray(rng.standard_normal((5 * PS, 2, 32)),
+                    jnp.float32).swapaxes(0, 1)
     row_idx = jnp.stack([jnp.asarray(PageTable(PS, MAX_KV, [2, 1]).row_idx()),
                          jnp.asarray(PageTable(PS, MAX_KV, []).row_idx())])
     pos = jnp.asarray([6, 0], jnp.int32)
@@ -364,3 +367,19 @@ def test_flash_attention_grad_flows():
     f = lambda q: ops.flash_attention(q, q, q).sum()
     val = f(q)
     assert np.isfinite(float(val))
+
+
+def test_flash_attention_grad_matches_oracle():
+    """The kernel's custom VJP (XLA recompute per batch row) gives the
+    oracle's gradients for causal and windowed attention."""
+    rng = np.random.default_rng(5)
+    q, k, v = (jnp.asarray(rng.standard_normal((2, 128, 2, 32)) * 0.5,
+                           jnp.float32) for _ in range(3))
+    for window in (0, 48):
+        f = lambda *a: (ops.flash_attention(*a, window=window) ** 2).sum()
+        r = lambda *a: (flash_attention_ref(*a, window=window) ** 2).sum()
+        got = jax.grad(f, argnums=(0, 1, 2))(q, k, v)
+        want = jax.grad(r, argnums=(0, 1, 2))(q, k, v)
+        for g, w in zip(got, want):
+            np.testing.assert_allclose(np.asarray(g), np.asarray(w),
+                                       atol=1e-4, rtol=1e-4)

@@ -168,7 +168,7 @@ def test_paged_step_materializes_no_gather_and_no_gqa_expansion():
     banned = {
         (b, max_kv, nkv, hd),           # gathered KV copy (pool heads)
         (b, max_kv, nq, hd),            # gathered + GQA-expanded copy
-        (num_rows, nq, hd),             # pool-sized head expansion
+        (nq, num_rows, hd),             # pool-sized head expansion
     }
     cache = mdl.init_paged_cache(cfg, b, num_rows)
     row_idx = jnp.stack([jnp.asarray(PageTable(4, max_kv, [1, 2]).row_idx()),
