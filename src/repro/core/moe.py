@@ -150,6 +150,16 @@ next plan's slots, ``publish_params`` the next version's (built on a
 background thread, overlapping in-flight decode steps) — and swaps the
 whole (plan, params, version, slots) state at a decode step boundary
 (see repro/serve/engine.py for the state machine).
+
+Trace names
+-----------
+The layer's device work carries ``jax.named_scope`` names, which the
+compiler keeps in each operation's ``op_name`` metadata: ``gate``,
+``dispatch`` (sort dispatch, capacity, the scatter into slots and the
+token all-to-all), ``expert_ffn``, ``combine``, and ``spag`` around every
+SparseAllGather.  The gather's AD transpose, spRS, reads as
+``transpose(...spag...)``; the explicit transposes of the backward
+re-gather and of the step-level stacked gather are scoped ``sprs``.
 """
 from __future__ import annotations
 
@@ -342,40 +352,42 @@ def gate(cfg: ModelConfig, wr: jnp.ndarray, x: jnp.ndarray,
     """x: (T, D); valid: (T,) bool.  Returns (idx:(T,k), vals:(T,k) f32,
     counts:(E,), aux_loss, z_loss).  With ``psum_axes`` (inside shard_map)
     the statistics are globalized with a single (E,)+scalars psum."""
-    k = cfg.moe.experts_per_token
-    e = cfg.moe.num_experts
-    logits = jnp.einsum("td,de->te", x.astype(jnp.float32),
-                        wr.astype(jnp.float32))
-    probs = jax.nn.softmax(logits, axis=-1)
-    vals, idx = jax.lax.top_k(probs, k)
-    vals = vals / jnp.maximum(vals.sum(-1, keepdims=True), 1e-9)
-    vals = vals * valid[:, None]
-    # per-expert token counts by scatter-add — the same trick the dispatch
-    # sort uses: the one-hot formulation materialized an O(T·k·E) tensor
-    # (the last one on the hot path); invalid entries land in an overflow
-    # bucket that is sliced off
-    cell = jnp.where(valid[:, None], idx, e).reshape(-1)
-    counts = jnp.zeros((e + 1,), jnp.float32).at[cell].add(1.0)[:e]
-    prob_sum = (probs * valid[:, None]).sum(0)                # (E,)
-    # the scalar statistics stay RANK-1 through the psum and divisions:
-    # shard_map's linearize-time partial eval on this jax version assigns
-    # residuals a leading device-axis spec that a rank-0 value cannot
-    # carry, breaking the AD transpose of the layer whenever the gate
-    # stats are differentiated (aux/z-loss in the training objective)
-    n_valid = valid.sum(keepdims=True).astype(jnp.float32)    # (1,)
-    z_sum = jnp.sum((jax.nn.logsumexp(logits, axis=-1) ** 2) * valid,
-                    keepdims=True)                            # (1,)
-    if psum_axes is not None:
-        counts, prob_sum, n_valid, z_sum = jax.lax.psum(
-            (counts, prob_sum, n_valid, z_sum), psum_axes)
-    n_valid = jnp.maximum(n_valid, 1.0)
-    # GShard aux: E * sum_e frac_e * mean_prob_e
-    frac = counts / jnp.maximum(counts.sum(), 1.0)
-    mean_prob = prob_sum / n_valid
-    aux = e * jnp.sum(jax.lax.stop_gradient(frac) * mean_prob[None, :],
-                      keepdims=True).reshape(1)
-    z = z_sum / n_valid
-    return idx, vals, counts, aux[0], z[0]
+    with jax.named_scope("gate"):
+        k = cfg.moe.experts_per_token
+        e = cfg.moe.num_experts
+        logits = jnp.einsum("td,de->te", x.astype(jnp.float32),
+                            wr.astype(jnp.float32))
+        probs = jax.nn.softmax(logits, axis=-1)
+        vals, idx = jax.lax.top_k(probs, k)
+        vals = vals / jnp.maximum(vals.sum(-1, keepdims=True), 1e-9)
+        vals = vals * valid[:, None]
+        # per-expert token counts by scatter-add — the same trick the
+        # dispatch sort uses: the one-hot formulation materialized an
+        # O(T·k·E) tensor (the last one on the hot path); invalid entries
+        # land in an overflow bucket that is sliced off
+        cell = jnp.where(valid[:, None], idx, e).reshape(-1)
+        counts = jnp.zeros((e + 1,), jnp.float32).at[cell].add(1.0)[:e]
+        prob_sum = (probs * valid[:, None]).sum(0)                # (E,)
+        # the scalar statistics stay RANK-1 through the psum and
+        # divisions: shard_map's linearize-time partial eval on this jax
+        # version assigns residuals a leading device-axis spec that a
+        # rank-0 value cannot carry, breaking the AD transpose of the
+        # layer whenever the gate stats are differentiated (aux/z-loss in
+        # the training objective)
+        n_valid = valid.sum(keepdims=True).astype(jnp.float32)    # (1,)
+        z_sum = jnp.sum((jax.nn.logsumexp(logits, axis=-1) ** 2) * valid,
+                        keepdims=True)                            # (1,)
+        if psum_axes is not None:
+            counts, prob_sum, n_valid, z_sum = jax.lax.psum(
+                (counts, prob_sum, n_valid, z_sum), psum_axes)
+        n_valid = jnp.maximum(n_valid, 1.0)
+        # GShard aux: E * sum_e frac_e * mean_prob_e
+        frac = counts / jnp.maximum(counts.sum(), 1.0)
+        mean_prob = prob_sum / n_valid
+        aux = e * jnp.sum(jax.lax.stop_gradient(frac) * mean_prob[None, :],
+                          keepdims=True).reshape(1)
+        z = z_sum / n_valid
+        return idx, vals, counts, aux[0], z[0]
 
 
 # ---------------------------------------------------------------------------
@@ -548,19 +560,20 @@ def _expert_ffn(cfg: ModelConfig, chunks, xr, use_pallas: bool,
     output rows so both values and gradients match the kernels' custom
     VJP exactly.
     """
-    wi, wg, wo = unpack_chunks(cfg, chunks)
-    dt = xr.dtype
-    if use_pallas:
-        from repro.kernels import ops as kops
-        return kops.grouped_mlp(xr, wi.astype(dt),
-                                None if wg is None else wg.astype(dt),
-                                wo.astype(dt), group_sizes, row_valid,
-                                act=cfg.act)
-    from repro.kernels.ref import grouped_mlp_ref
-    return grouped_mlp_ref(xr, wi.astype(dt),
-                           None if wg is None else wg.astype(dt),
-                           wo.astype(dt), act=cfg.act,
-                           group_sizes=group_sizes, row_valid=row_valid)
+    with jax.named_scope("expert_ffn"):
+        wi, wg, wo = unpack_chunks(cfg, chunks)
+        dt = xr.dtype
+        if use_pallas:
+            from repro.kernels import ops as kops
+            return kops.grouped_mlp(xr, wi.astype(dt),
+                                    None if wg is None else wg.astype(dt),
+                                    wo.astype(dt), group_sizes, row_valid,
+                                    act=cfg.act)
+        from repro.kernels.ref import grouped_mlp_ref
+        return grouped_mlp_ref(xr, wi.astype(dt),
+                               None if wg is None else wg.astype(dt),
+                               wo.astype(dt), act=cfg.act,
+                               group_sizes=group_sizes, row_valid=row_valid)
 
 
 # ---------------------------------------------------------------------------
@@ -600,8 +613,9 @@ def _moe_body(cfg: ModelConfig, impl: str, ep_axis: str, fsdp_axes,
         # policies would save the same chunks twice
         chunks = premat[0]                           # (K, chunk_len)
     else:
-        chunks = _materialize(cfg, buf, pa, impl, ep_axis, fsdp_axes, m,
-                              batch=batch_coll)
+        with jax.named_scope("spag"):
+            chunks = _materialize(cfg, buf, pa, impl, ep_axis, fsdp_axes,
+                                  m, batch=batch_coll)
         chunks = checkpoint_name(chunks, "moe_materialized")
 
     idx, vals, counts, aux, z = gate(cfg, wr, x, valid,
@@ -609,73 +623,83 @@ def _moe_body(cfg: ModelConfig, impl: str, ep_axis: str, fsdp_axes,
     k = idx.shape[1]
 
     # ---- dispatch plan (§4.4: local replica first, else round-robin) ----
-    e_flat = idx.reshape(-1)                                   # (T*k,)
-    w_flat = vals.reshape(-1)
-    valid_w = w_flat > 0
-    e_safe = jnp.maximum(e_flat, 0)
-    tk = e_flat.shape[0]
-    cap_eff = M * capacity if impl == "dense" else capacity
-    if impl == "dense":
-        # every expert local: pure data parallelism for the MoE (FSDP).
-        # Cells are slots; one expert per slot, so pos = per-expert rank
-        # (counting valid entries only — kept rows stay a cell prefix).
-        dest = jnp.full((tk,), me, jnp.int32)
-        slot = jnp.take(pa.expert_slot[me], e_safe)
-        pos = segment_ranks(jnp.where(valid_w, e_safe,
-                                      cfg.moe.num_experts))
-        keep = valid_w & (pos < cap_eff) & (slot >= 0)
-        cnt = jnp.zeros((K + 1,), jnp.int32).at[
-            jnp.where(keep, slot, K)].add(1)[:K]
-    else:
-        dest, slot, pos, keep, send_cnt = replica_dispatch(
-            e_safe, valid_w, pa.expert_slot, pa.replicas, pa.n_replicas,
-            me, K, cap_eff, local_first)
-    dropped = 1.0 - keep.sum() / jnp.maximum(valid_w.sum(), 1)
-    pos_w = jnp.where(keep, pos, cap_eff)                      # OOB -> dropped
-    xtok = x[jnp.arange(tk) // k]
+    with jax.named_scope("dispatch"):
+        e_flat = idx.reshape(-1)                               # (T*k,)
+        w_flat = vals.reshape(-1)
+        valid_w = w_flat > 0
+        e_safe = jnp.maximum(e_flat, 0)
+        tk = e_flat.shape[0]
+        cap_eff = M * capacity if impl == "dense" else capacity
+        if impl == "dense":
+            # every expert local: pure data parallelism for the MoE (FSDP).
+            # Cells are slots; one expert per slot, so pos = per-expert
+            # rank (counting valid entries only — kept rows stay a cell
+            # prefix).
+            dest = jnp.full((tk,), me, jnp.int32)
+            slot = jnp.take(pa.expert_slot[me], e_safe)
+            pos = segment_ranks(jnp.where(valid_w, e_safe,
+                                          cfg.moe.num_experts))
+            keep = valid_w & (pos < cap_eff) & (slot >= 0)
+            cnt = jnp.zeros((K + 1,), jnp.int32).at[
+                jnp.where(keep, slot, K)].add(1)[:K]
+        else:
+            dest, slot, pos, keep, send_cnt = replica_dispatch(
+                e_safe, valid_w, pa.expert_slot, pa.replicas,
+                pa.n_replicas, me, K, cap_eff, local_first)
+        dropped = 1.0 - keep.sum() / jnp.maximum(valid_w.sum(), 1)
+        pos_w = jnp.where(keep, pos, cap_eff)              # OOB -> dropped
+        xtok = x[jnp.arange(tk) // k]
 
     if impl == "dense":
         # no token communication at all — local (K, M*C, D) compute buffer;
         # positions are a per-slot valid prefix, so the kept counts are the
         # group sizes directly
-        gs = cnt                                               # (K,)
-        buf_x = jnp.zeros((K, cap_eff, D), x.dtype)
-        buf_x = buf_x.at[slot, pos_w].set(xtok, mode="drop")
+        with jax.named_scope("dispatch"):
+            gs = cnt                                           # (K,)
+            buf_x = jnp.zeros((K, cap_eff, D), x.dtype)
+            buf_x = buf_x.at[slot, pos_w].set(xtok, mode="drop")
         yr = _expert_ffn(cfg, chunks, buf_x, use_pallas, group_sizes=gs)
-        got = yr[slot, pos_w] * keep[:, None].astype(x.dtype)
-        dev_loads_l = jnp.zeros((M,), jnp.float32).at[me].set(
-            gs.sum().astype(jnp.float32))
+        with jax.named_scope("combine"):
+            got = yr[slot, pos_w] * keep[:, None].astype(x.dtype)
+            dev_loads_l = jnp.zeros((M,), jnp.float32).at[me].set(
+                gs.sum().astype(jnp.float32))
         rows_per_dev = K * cap_eff
     else:
-        send = jnp.zeros((M, K, capacity, D), x.dtype)
-        send = send.at[dest, slot, pos_w].set(xtok, mode="drop")
-        recv = jax.lax.all_to_all(send, ep_axis, 0, 0, tiled=False)  # (M,K,C,D)
-        xr = recv.transpose(1, 0, 2, 3).reshape(K, M * capacity, D)
-        if use_pallas:
-            # per-row validity rides a tiny (M, K) int all_to_all; the
-            # dispatch lands kept tokens in a valid prefix of each source's
-            # capacity stripe, so validity is metadata — the kernels skip
-            # token tiles with no valid row directly in the uncompacted
-            # layout (no (K, T, D) gather/scatter compaction copies)
-            recv_cnt = jax.lax.all_to_all(send_cnt, ep_axis, 0, 0,
-                                          tiled=False)         # (M, K)
-            r_src = jnp.arange(M * capacity, dtype=jnp.int32) // capacity
-            r_off = jnp.arange(M * capacity, dtype=jnp.int32) % capacity
-            valid_row = r_off[None, :] < recv_cnt.T[:, r_src]  # (K, M*C)
-            yr = _expert_ffn(cfg, chunks, xr, True, row_valid=valid_row)
-        else:
-            yr = _expert_ffn(cfg, chunks, xr, False)
-        yback = yr.reshape(K, M, capacity, D).transpose(1, 0, 2, 3)
-        ret = jax.lax.all_to_all(yback, ep_axis, 0, 0, tiled=False)
-        got = ret[dest, slot, pos_w] * keep[:, None].astype(x.dtype)
-        dev_loads_l = send_cnt.sum(1).astype(jnp.float32)
+        with jax.named_scope("dispatch"):
+            send = jnp.zeros((M, K, capacity, D), x.dtype)
+            send = send.at[dest, slot, pos_w].set(xtok, mode="drop")
+            recv = jax.lax.all_to_all(send, ep_axis, 0, 0,
+                                      tiled=False)             # (M,K,C,D)
+            xr = recv.transpose(1, 0, 2, 3).reshape(K, M * capacity, D)
+            valid_row = None
+            if use_pallas:
+                # per-row validity rides a tiny (M, K) int all_to_all; the
+                # dispatch lands kept tokens in a valid prefix of each
+                # source's capacity stripe, so validity is metadata — the
+                # kernels skip token tiles with no valid row directly in
+                # the uncompacted layout (no (K, T, D) gather/scatter
+                # compaction copies)
+                recv_cnt = jax.lax.all_to_all(send_cnt, ep_axis, 0, 0,
+                                              tiled=False)     # (M, K)
+                r_src = jnp.arange(M * capacity,
+                                   dtype=jnp.int32) // capacity
+                r_off = jnp.arange(M * capacity,
+                                   dtype=jnp.int32) % capacity
+                valid_row = r_off[None, :] < recv_cnt.T[:, r_src]
+        yr = _expert_ffn(cfg, chunks, xr, use_pallas, row_valid=valid_row)
+        with jax.named_scope("combine"):
+            yback = yr.reshape(K, M, capacity, D).transpose(1, 0, 2, 3)
+            ret = jax.lax.all_to_all(yback, ep_axis, 0, 0, tiled=False)
+            got = ret[dest, slot, pos_w] * keep[:, None].astype(x.dtype)
+            dev_loads_l = send_cnt.sum(1).astype(jnp.float32)
         rows_per_dev = K * M * capacity
 
-    y = (got.reshape(T, k, D)
-         * vals.reshape(T, k, 1).astype(x.dtype)).sum(axis=1)
-    dev_loads = jax.lax.psum(dev_loads_l, all_axes)
-    n_dev = jax.lax.psum(1, all_axes)
-    pad_frac = 1.0 - dev_loads.sum() / float(rows_per_dev * n_dev)
+    with jax.named_scope("combine"):
+        y = (got.reshape(T, k, D)
+             * vals.reshape(T, k, 1).astype(x.dtype)).sum(axis=1)
+        dev_loads = jax.lax.psum(dev_loads_l, all_axes)
+        n_dev = jax.lax.psum(1, all_axes)
+        pad_frac = 1.0 - dev_loads.sum() / float(rows_per_dev * n_dev)
     return y, counts, aux, z, dropped, dev_loads, pad_frac
 
 
@@ -923,9 +947,10 @@ def moe_layer_regather_pipelined(cfg: ModelConfig, rt: MoERuntime, x, wr,
         # gather lands the chunk cotangent on the owning buffer shards —
         # nothing in this layer consumes it, so it sits off the critical
         # path of the backward pipeline
-        dbuf = jax.linear_transpose(
-            lambda b: materialize_layer(cfg, rt, b, pa_, dtype=dch.dtype,
-                                        name=False), buf_)(dch)[0]
+        with jax.named_scope("sprs"):
+            dbuf = jax.linear_transpose(
+                lambda b: materialize_layer(cfg, rt, b, pa_, dtype=dch.dtype,
+                                            name=False), buf_)(dch)[0]
         return dx, dwr, dbuf.astype(buf_.dtype), prev, None, None, None, \
             None
 
@@ -961,23 +986,24 @@ def materialize_layer(cfg: ModelConfig, rt: MoERuntime, buf,
     ``moe_layer_regather_pipelined``'s VJP, whose explicit transpose is the
     SparseReduceScatter landing the buffer gradient.
     """
-    buf, _ = unwrap_buffer(buf)
-    buf = buf.astype(dtype or jnp.dtype(cfg.dtype))
-    m = _m_of(rt, pa_l)
-    batch = _coll_batch(rt)
+    with jax.named_scope("spag"):
+        buf, _ = unwrap_buffer(buf)
+        buf = buf.astype(dtype or jnp.dtype(cfg.dtype))
+        m = _m_of(rt, pa_l)
+        batch = _coll_batch(rt)
 
-    def body(buf_, pa_):
-        ch = _materialize(cfg, buf_, pa_, rt.impl, rt.ep_axis,
-                          rt.fsdp_axes, m, batch=batch)
-        return ch[None]                              # (1, K, chunk_len)
+        def body(buf_, pa_):
+            ch = _materialize(cfg, buf_, pa_, rt.impl, rt.ep_axis,
+                              rt.fsdp_axes, m, batch=batch)
+            return ch[None]                              # (1, K, chunk_len)
 
-    out = jax.shard_map(
-        body, mesh=rt.mesh,
-        in_specs=(P(rt.ep_axis, rt.fsdp_axes),
-                  plan_arrays_specs(rt.mesh, rt.ep_axis)),
-        out_specs=P(rt.ep_axis, None, None),
-        check_vma=False)(buf, pa_l)
-    return checkpoint_name(out, "moe_materialized") if name else out
+        out = jax.shard_map(
+            body, mesh=rt.mesh,
+            in_specs=(P(rt.ep_axis, rt.fsdp_axes),
+                      plan_arrays_specs(rt.mesh, rt.ep_axis)),
+            out_specs=P(rt.ep_axis, None, None),
+            check_vma=False)(buf, pa_l)
+        return checkpoint_name(out, "moe_materialized") if name else out
 
 
 def materialize_stack(cfg: ModelConfig, rt: MoERuntime, buf, pa: PlanArrays,
@@ -1000,29 +1026,30 @@ def materialize_stack(cfg: ModelConfig, rt: MoERuntime, buf, pa: PlanArrays,
     on the owning buffer shards, once per step.  ``materialize_chunks``
     wraps this body in a cached jit for the serving path.
     """
-    buf, _ = unwrap_buffer(buf)
-    dt = jnp.dtype(dtype or jnp.dtype(cfg.dtype))
-    m = _m_of(rt, pa)
-    batch = _coll_batch(rt)
-    L = pa.local_rows.shape[0]
+    with jax.named_scope("spag"):
+        buf, _ = unwrap_buffer(buf)
+        dt = jnp.dtype(dtype or jnp.dtype(cfg.dtype))
+        m = _m_of(rt, pa)
+        batch = _coll_batch(rt)
+        L = pa.local_rows.shape[0]
 
-    def body(buf_, pa_):
-        buf_ = buf_.astype(dt)
-        outs = [_materialize(cfg, buf_,
-                             jax.tree.map(lambda a, l=l: a[l], pa_),
-                             rt.impl, rt.ep_axis, rt.fsdp_axes, m,
-                             batch=batch)
-                for l in range(L)]
-        return jnp.stack(outs)[:, None]              # (L, 1, K, chunk_len)
+        def body(buf_, pa_):
+            buf_ = buf_.astype(dt)
+            outs = [_materialize(cfg, buf_,
+                                 jax.tree.map(lambda a, l=l: a[l], pa_),
+                                 rt.impl, rt.ep_axis, rt.fsdp_axes, m,
+                                 batch=batch)
+                    for l in range(L)]
+            return jnp.stack(outs)[:, None]          # (L, 1, K, chunk_len)
 
-    specs = plan_arrays_specs(rt.mesh, rt.ep_axis)
-    stacked = PlanArrays(*[P(None, *tuple(s)) for s in specs])
-    out = jax.shard_map(
-        body, mesh=rt.mesh,
-        in_specs=(P(rt.ep_axis, rt.fsdp_axes), stacked),
-        out_specs=P(None, rt.ep_axis, None, None),
-        check_vma=False)(buf, pa)
-    return checkpoint_name(out, "moe_materialized") if name else out
+        specs = plan_arrays_specs(rt.mesh, rt.ep_axis)
+        stacked = PlanArrays(*[P(None, *tuple(s)) for s in specs])
+        out = jax.shard_map(
+            body, mesh=rt.mesh,
+            in_specs=(P(rt.ep_axis, rt.fsdp_axes), stacked),
+            out_specs=P(None, rt.ep_axis, None, None),
+            check_vma=False)(buf, pa)
+        return checkpoint_name(out, "moe_materialized") if name else out
 
 
 # jitted stacked-materialize cache: plans change CONTENTS every iteration

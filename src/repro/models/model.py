@@ -265,18 +265,20 @@ def _superblock(cfg: ModelConfig, rt: Runtime, params_sb, x, positions,
                     y, c = y
                 x2 = x_ + y
             else:
-                y = attn.attention(p_["attn"], cfg, h, positions, kind=kind,
-                                   causal=causal, use_pallas=rt.use_pallas,
-                                   return_kv=collect_cache, mesh=rt.mesh,
-                                   rules=rt.rules)
-                if collect_cache:
-                    y, c = y
-                x2 = x_ + y
-                if enc_out_ is not None:
-                    hx = ly.apply_norm(p_["lnx"], x2, cfg.norm)
-                    x2 = x2 + attn.attention(p_["xattn"], cfg, hx,
-                                             positions, causal=False,
-                                             xa=enc_out_)
+                with jax.named_scope("attention"):
+                    y = attn.attention(p_["attn"], cfg, h, positions,
+                                       kind=kind, causal=causal,
+                                       use_pallas=rt.use_pallas,
+                                       return_kv=collect_cache,
+                                       mesh=rt.mesh, rules=rt.rules)
+                    if collect_cache:
+                        y, c = y
+                    x2 = x_ + y
+                    if enc_out_ is not None:
+                        hx = ly.apply_norm(p_["lnx"], x2, cfg.norm)
+                        x2 = x2 + attn.attention(p_["xattn"], cfg, hx,
+                                                 positions, causal=False,
+                                                 xa=enc_out_)
             return x2, c
 
         if seg_remat:
@@ -539,8 +541,9 @@ def forward(cfg: ModelConfig, rt: Runtime, params, tokens=None, *,
     """
     dt = jnp.dtype(cfg.dtype)
     if embeds is None:
-        x = ly.embed(params["embed"], tokens, dt)
-        x = x * math.sqrt(cfg.d_model)
+        with jax.named_scope("lm_head"):
+            x = ly.embed(params["embed"], tokens, dt)
+            x = x * math.sqrt(cfg.d_model)
     else:
         x = embeds.astype(dt)
     b, s = x.shape[:2]
@@ -587,13 +590,15 @@ def forward(cfg: ModelConfig, rt: Runtime, params, tokens=None, *,
         if moe_xs is not None:
             xs = (params["blocks"], (moe_xs[0], moe_xs[1]))
         x, ys = _scan(rt, body, x, xs)
-    x = ly.apply_norm(params["final_norm"], x, cfg.norm)
+    with jax.named_scope("lm_head"):
+        x = ly.apply_norm(params["final_norm"], x, cfg.norm)
     if return_hidden:
         # loss is computed chunked from the hidden states (train path):
         # materializing full (B, S, V) f32 logits costs tens of GB/device
         # for 150k-vocab models (seen in the qwen-110b dry-run).
         return x, ys
-    logits = ly.unembed(params["embed"], x, cfg.final_logit_softcap)
+    with jax.named_scope("lm_head"):
+        logits = ly.unembed(params["embed"], x, cfg.final_logit_softcap)
     if collect_cache:
         aux_stack, cache = ys if ys is not None else (None, {})
         if cfg.is_encoder_decoder:

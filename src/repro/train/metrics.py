@@ -1,4 +1,4 @@
-"""Training metrics: JSONL sink, moving averages, throughput, the
+"""Training metrics: JSONL sink, moving averages, the
 FSSDP load-balance observables (expert counts entropy, device-load
 imbalance) that the paper's Figure 3 tracks, and the robustness counters
 (`RobustnessCounters`) the fault-tolerance layer surfaces per step."""
@@ -7,7 +7,6 @@ from __future__ import annotations
 import dataclasses
 import json
 import os
-import time
 from collections import deque
 from typing import Any, Dict, Optional
 
@@ -120,22 +119,20 @@ def device_stats(loads: np.ndarray) -> Dict[str, float]:
 
 
 class MetricLogger:
-    def __init__(self, path: Optional[str] = None, window: int = 20,
-                 tokens_per_step: float = 0.0):
+    """Appends one JSON record per step: the scalar metrics it is given
+    (``train_loop`` passes the step's ``time_s`` among them), the load
+    observables, and a moving average of the loss."""
+
+    def __init__(self, path: Optional[str] = None, window: int = 20):
         self.path = path
         self._fh = None
         if path:
             os.makedirs(os.path.dirname(path) or ".", exist_ok=True)
             self._fh = open(path, "a")
         self.window = deque(maxlen=window)
-        self.tokens_per_step = tokens_per_step
-        self._t_last = time.perf_counter()
 
     def log(self, step: int, metrics: Dict[str, Any]) -> Dict[str, Any]:
-        now = time.perf_counter()
-        dt = now - self._t_last
-        self._t_last = now
-        rec: Dict[str, Any] = {"step": step, "time_s": dt}
+        rec: Dict[str, Any] = {"step": step}
         for k, v in metrics.items():
             a = np.asarray(v)
             if a.ndim == 0:
@@ -144,8 +141,6 @@ class MetricLogger:
             rec.update(expert_stats(np.asarray(metrics["expert_counts"])))
         if "device_loads" in metrics:
             rec.update(device_stats(np.asarray(metrics["device_loads"])))
-        if self.tokens_per_step:
-            rec["tokens_per_s"] = self.tokens_per_step / max(dt, 1e-9)
         self.window.append(rec.get("loss", 0.0))
         rec["loss_avg"] = float(np.mean(self.window))
         if self._fh:

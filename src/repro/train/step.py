@@ -126,7 +126,8 @@ def loss_fn(cfg: ModelConfig, rt: mdl.Runtime, params, batch,
     kwargs, labels = _unpack_batch(cfg, batch)
     hidden, aux = mdl.forward(cfg, rt, params, pa=pa, causal=causal,
                               return_hidden=True, premat=premat, **kwargs)
-    loss = chunked_xent(cfg, params["embed"], hidden, labels)
+    with jax.named_scope("lm_head"):
+        loss = chunked_xent(cfg, params["embed"], hidden, labels)
     metrics = {"xent": loss}
     if aux is not None:
         # aux leaves: (n_sb, c, ...) -> (L_moe, ...)
@@ -250,10 +251,11 @@ def build_train_step(cfg: ModelConfig, rt: mdl.Runtime, tc: TrainConfig,
                 # stacked SparseReduceScatter: ONE transpose of the
                 # step-level gather lands the accumulated chunk cotangent
                 # on the owning buffer shards
-                dbuf = jax.linear_transpose(
-                    lambda b: moe_core.materialize_stack(
-                        cfg, rt.moe, b, pa, dtype=dt, name=False),
-                    state.params["moe_buffer"])(gpm.astype(dt))[0]
+                with jax.named_scope("sprs"):
+                    dbuf = jax.linear_transpose(
+                        lambda b: moe_core.materialize_stack(
+                            cfg, rt.moe, b, pa, dtype=dt, name=False),
+                        state.params["moe_buffer"])(gpm.astype(dt))[0]
                 grads = dict(grads)
                 grads["moe_buffer"] = grads["moe_buffer"] \
                     + dbuf.astype(jnp.float32) * inv
@@ -266,10 +268,12 @@ def build_train_step(cfg: ModelConfig, rt: mdl.Runtime, tc: TrainConfig,
         # a non-finite loss or grad global norm.  The gnorm is already on
         # the clipping path and step_ok rides the step's one metrics
         # readback — no extra device sync.
-        extra_ok = jnp.isfinite(metrics["loss"]) if tc.step_guard else None
-        new_params, new_opt, opt_metrics = adamw.update(
-            grads, state.opt, state.params, tc,
-            skip_nonfinite=tc.step_guard, extra_ok=extra_ok)
+        with jax.named_scope("optimizer"):
+            extra_ok = (jnp.isfinite(metrics["loss"]) if tc.step_guard
+                        else None)
+            new_params, new_opt, opt_metrics = adamw.update(
+                grads, state.opt, state.params, tc,
+                skip_nonfinite=tc.step_guard, extra_ok=extra_ok)
         metrics.update(opt_metrics)
         return TrainState(new_params, new_opt, state.step + 1), metrics
 

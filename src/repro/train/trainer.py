@@ -37,9 +37,20 @@ publishes per-device speed weights that flow into
 ``schedule.heterogeneous_sharding(device_weights=)`` at the next reshard
 (and into the calibration cost model), shrinking the straggler's expert
 slot share proportionally.
+
+Tracing: each iteration of ``train_loop`` is a ``hecate.step`` profiler
+step with one ``jax.profiler.TraceAnnotation`` per phase (``hecate.batch``,
+``.upload``, ``.reshard``, ``.plan``, ``.dispatch``, ``.publish``,
+``.plan_ahead``, ``.readback``, ``.observe``, ``.callback``,
+``.checkpoint``); ``HecateScheduler`` adds the children of ``hecate.plan``
+(``.wait``, ``.alg1``, ``.tables``, ``.to_device``) and of
+``hecate.observe`` (``hecate.calibrate``), and its worker thread's
+``hecate.worker.alg1`` / ``.tables`` spans carry the step they plan for.
+They cost nothing measurable without a profiler session.
 """
 from __future__ import annotations
 
+import contextlib
 import dataclasses
 import os
 import queue
@@ -208,20 +219,22 @@ class HecateScheduler:
             self._executor = _PlanWorker()
         return self._executor
 
-    def plan_ahead(self) -> None:
+    def plan_ahead(self, step: Optional[int] = None) -> None:
         """Kick off computing the NEXT step's materialization plan — AND
         its runtime tables — on the background thread.  Call right after
         dispatching the train step: the Alg-1 greedy and the
         ``plan_tables`` build then overlap the device computation, leaving
         only the device transfer on the critical path.  The prediction is
         snapshotted on the caller's thread so the worker never races
-        predictor updates."""
+        predictor updates.  ``step``, the global step the plan is for,
+        tags the worker's ``hecate.worker.*`` trace spans."""
         if not self.async_plan or self.impl == "ep":
             return
         if self._pending is not None:       # one in flight is plenty
             return
         pred = self.predictor.predict()
         sh = self.sharding
+        tag = {} if step is None else {"step": step}
 
         def job():
             # chaos sites (repro.common.faults): an armed exception/hang
@@ -229,10 +242,12 @@ class HecateScheduler:
             # training loop
             faults.fire("scheduler.plan_job")
             faults.fire("scheduler.plan_job_hang")
-            plan = sparse_materialization(
-                sh, pred, t=self.t, m=self.cfg.moe.slots_per_device,
-                impl=self.impl)
-            return plan, moe_core.plan_tables(plan)
+            with jax.profiler.TraceAnnotation("hecate.worker.alg1", **tag):
+                plan = sparse_materialization(
+                    sh, pred, t=self.t, m=self.cfg.moe.slots_per_device,
+                    impl=self.impl)
+            with jax.profiler.TraceAnnotation("hecate.worker.tables", **tag):
+                return plan, moe_core.plan_tables(plan)
 
         self._pending = (self._pool().submit(job), sh)
 
@@ -309,33 +324,52 @@ class HecateScheduler:
             plan, self._calibrated = self._calibrated, None
             self._drop_pending()
         else:
-            got = self._take_pending()
+            got = None
+            if self._pending is not None:
+                with jax.profiler.TraceAnnotation("hecate.plan.wait"):
+                    got = self._take_pending()
             if got is not None:
                 plan, self._prefetched_tables = got
                 self.plan_ahead_hits += 1
             else:
-                plan = sparse_materialization(
-                    self.sharding, self.predictor.predict(), t=self.t,
-                    m=self.cfg.moe.slots_per_device, impl=self.impl)
+                with jax.profiler.TraceAnnotation("hecate.plan.alg1"):
+                    plan = sparse_materialization(
+                        self.sharding, self.predictor.predict(), t=self.t,
+                        m=self.cfg.moe.slots_per_device, impl=self.impl)
         self._last_plan = plan
         return plan
+
+    def _plan_source(self) -> str:
+        """Where the next ``plan()`` comes from, as known before it runs.
+        A prefetch that turns out stale or failed still falls back to a
+        synchronous Alg 1, which its ``hecate.plan.alg1`` span shows."""
+        if self.impl != "ep" and self._calibrated is not None:
+            return "calibrated"
+        if self.impl != "ep" and self._pending is not None:
+            return "prefetch"
+        return "sync"
 
     def plan_arrays(self) -> moe_core.PlanArrays:
         """Device tables for the next step — from the plan-ahead thread's
         prefetched numpy tables when available (only the host->device
         transfer remains on the critical path)."""
-        plan = self.plan()
-        tables, self._prefetched_tables = self._prefetched_tables, None
-        if tables is None:
-            tables = moe_core.plan_tables(plan)
-        return moe_core.tables_to_device(tables)
+        with jax.profiler.TraceAnnotation("hecate.plan",
+                                          source=self._plan_source()):
+            plan = self.plan()
+            tables, self._prefetched_tables = self._prefetched_tables, None
+            if tables is None:
+                with jax.profiler.TraceAnnotation("hecate.plan.tables"):
+                    tables = moe_core.plan_tables(plan)
+            with jax.profiler.TraceAnnotation("hecate.plan.to_device"):
+                return moe_core.tables_to_device(tables)
 
     def observe(self, counts: np.ndarray) -> None:
         counts = np.asarray(counts, np.float64)
         self.predictor.observe(counts)
         if (self.calibrate and self.impl in ("ring", "a2a")
                 and self._last_plan is not None):
-            self._maybe_calibrate(counts)
+            with jax.profiler.TraceAnnotation("hecate.calibrate"):
+                self._maybe_calibrate(counts)
 
     def _maybe_calibrate(self, real_loads: np.ndarray) -> None:
         from repro.core.costs import CostContext, calibration_gain
@@ -730,29 +764,40 @@ def train_loop(cfg: ModelConfig, rt, tc: TrainConfig,
         if supervisor is not None else None
     pending = deque()
     last_pub_version = 0            # monotone guard across rollbacks
+    # one hecate.step trace span per iteration, from its start to the
+    # next one's (or the loop's end); each phase has a span of its own
+    step_span = contextlib.ExitStack()
     try:
         i = start
         while i < num_steps:
             gstep = step_base + (i - start) + 1     # global step AFTER i
-            raw = pending.popleft() if pending else next(it)
+            step_span.close()
+            step_span.enter_context(jax.profiler.StepTraceAnnotation(
+                "hecate.step", step_num=gstep))
+            with jax.profiler.TraceAnnotation("hecate.batch"):
+                raw = pending.popleft() if pending else next(it)
             if replay is not None:
                 replay.append((i, raw))
-            batch = {k: jnp.asarray(v) for k, v in raw.items()}
+            with jax.profiler.TraceAnnotation("hecate.upload"):
+                batch = {k: jnp.asarray(v) for k, v in raw.items()}
             # chaos site: tests arm this with faults.poison_grads to make
             # THIS step's gradients NaN (see repro.common.faults)
             batch = faults.fire("train.nan_grads", batch)
             pa = None
             if scheduler is not None and cfg.moe.enabled:
-                if supervisor is not None:
-                    scheduler.device_weights = supervisor.device_weights()
-                perm = scheduler.maybe_reshard(i)
-                if perm is not None:
-                    state = apply_reshard(state, perm)
-                    pending_replan = True
-                pa = scheduler.plan_arrays()
+                with jax.profiler.TraceAnnotation("hecate.reshard"):
+                    if supervisor is not None:
+                        scheduler.device_weights = \
+                            supervisor.device_weights()
+                    perm = scheduler.maybe_reshard(i)
+                    if perm is not None:
+                        state = apply_reshard(state, perm)
+                        pending_replan = True
+                pa = scheduler.plan_arrays()        # span: hecate.plan
             t0 = time.perf_counter()
             # async dispatch: the call returns with the step in flight
-            state, metrics = train_step_fn(state, batch, pa)
+            with jax.profiler.TraceAnnotation("hecate.dispatch"):
+                state, metrics = train_step_fn(state, batch, pa)
             if (publish_engine is not None and publish_every
                     and (i + 1) % publish_every == 0
                     # after an elastic rollback the replayed steps revisit
@@ -771,13 +816,14 @@ def train_loop(cfg: ModelConfig, rt, tc: TrainConfig,
                 # is dropped (counted), and a closed engine disables
                 # further publications for this run.
                 try:
-                    if pending_replan and pa is not None:
-                        publish_engine.publish_params(
-                            state.params, version=gstep, pa=pa)
-                        pending_replan = False
-                    else:
-                        publish_engine.publish_params(
-                            state.params, version=gstep)
+                    with jax.profiler.TraceAnnotation("hecate.publish"):
+                        if pending_replan and pa is not None:
+                            publish_engine.publish_params(
+                                state.params, version=gstep, pa=pa)
+                            pending_replan = False
+                        else:
+                            publish_engine.publish_params(
+                                state.params, version=gstep)
                     last_pub_version = gstep
                 except Exception as e:
                     loop_pub_failures += 1
@@ -792,8 +838,10 @@ def train_loop(cfg: ModelConfig, rt, tc: TrainConfig,
             if (scheduler is not None and cfg.moe.enabled
                     and i + 1 < num_steps):
                 # plan step i+1 while step i runs on-device
-                scheduler.plan_ahead()
-            metrics = jax.tree.map(np.asarray, metrics)  # blocks on step
+                with jax.profiler.TraceAnnotation("hecate.plan_ahead"):
+                    scheduler.plan_ahead(step=gstep + 1)
+            with jax.profiler.TraceAnnotation("hecate.readback"):
+                metrics = jax.tree.map(np.asarray, metrics)  # blocks
             dt = time.perf_counter() - t0
             if supervisor is not None:
                 try:
@@ -856,7 +904,8 @@ def train_loop(cfg: ModelConfig, rt, tc: TrainConfig,
                     i = i_resume
                     continue
             if scheduler is not None and "expert_counts" in metrics:
-                scheduler.observe(metrics["expert_counts"])
+                with jax.profiler.TraceAnnotation("hecate.observe"):
+                    scheduler.observe(metrics["expert_counts"])
             # ---- step-health skip policy (rides the readback above) ----
             step_ok = float(metrics.get("step_ok", 1.0)) >= 0.5
             if not step_ok:
@@ -886,10 +935,11 @@ def train_loop(cfg: ModelConfig, rt, tc: TrainConfig,
             if "pad_frac" in metrics:
                 rec["pad_frac"] = float(metrics["pad_frac"])
             if metric_logger is not None:
-                rec.update(metric_logger.log(i, metrics))
+                rec.update(metric_logger.log(i, {**metrics, "time_s": dt}))
             history.append(rec)
             if callback:
-                callback(i, state, metrics)
+                with jax.profiler.TraceAnnotation("hecate.callback"):
+                    callback(i, state, metrics)
             if bad_streak >= tc.max_bad_steps > 0:
                 # budget exhausted: roll back to the last intact
                 # checkpoint (params poisoned-in-flight are abandoned)
@@ -914,7 +964,8 @@ def train_loop(cfg: ModelConfig, rt, tc: TrainConfig,
                     state=state, history=history, step=gstep)
             if (tc.checkpoint_dir and tc.checkpoint_every
                     and step_ok and gstep % tc.checkpoint_every == 0):
-                save_train_state(tc, gstep, state, scheduler)
+                with jax.profiler.TraceAnnotation("hecate.checkpoint"):
+                    save_train_state(tc, gstep, state, scheduler)
                 if supervisor is not None and supervisor.can_grow_back():
                     # the lost device rejoined (its fault site cleared):
                     # grow back to the full ep at this checkpoint
@@ -960,6 +1011,7 @@ def train_loop(cfg: ModelConfig, rt, tc: TrainConfig,
                       f"xent {rec['xent']:.4f}  {dt*1e3:.0f} ms")
             i += 1
     finally:
+        step_span.close()
         if scheduler is not None:
             # join the plan-ahead worker; the executor is re-created
             # lazily, so a scheduler reused across train_loop calls keeps

@@ -30,15 +30,17 @@ def test_device_stats():
 
 def test_metric_logger_jsonl(tmp_path):
     path = str(tmp_path / "m.jsonl")
-    ml = MetricLogger(path, tokens_per_step=1024)
-    rec = ml.log(0, {"loss": jnp.float32(2.0),
+    ml = MetricLogger(path)
+    rec = ml.log(0, {"loss": jnp.float32(2.0), "time_s": 0.25,
                      "expert_counts": np.ones((2, 4)),
                      "device_loads": np.ones((2, 2))})
     ml.close()
-    assert rec["loss"] == 2.0 and "tokens_per_s" in rec
+    # the caller's step time survives; the logger keeps no clock of its own
+    assert rec["loss"] == 2.0 and rec["time_s"] == 0.25
+    assert "tokens_per_s" not in rec
     assert rec["expert_entropy_frac"] > 0.99
     on_disk = [json.loads(l) for l in open(path)]
-    assert on_disk[0]["step"] == 0
+    assert on_disk[0]["step"] == 0 and on_disk[0]["time_s"] == 0.25
 
 
 def test_train_loop_with_metric_logger(tmp_path):
@@ -46,14 +48,18 @@ def test_train_loop_with_metric_logger(tmp_path):
     tc = TrainConfig(learning_rate=1e-3, warmup_steps=2, total_steps=4)
     stream = make_stream(cfg.vocab_size, 16, 4, seed=0)
     sched = HecateScheduler(cfg, ep=1, impl="ep")
-    ml = MetricLogger(str(tmp_path / "train.jsonl"),
-                      tokens_per_step=4 * 16)
+    ml = MetricLogger(str(tmp_path / "train.jsonl"))
     state, hist = train_loop(cfg, Runtime(), tc, stream, scheduler=sched,
                              num_steps=4, log_every=0, metric_logger=ml)
     ml.close()
     recs = [json.loads(l) for l in open(tmp_path / "train.jsonl")]
     assert len(recs) == 4
     assert "device_straggler_factor" in recs[0]
+    # the loop's time_s (dispatch to read-back) survives the logger, in
+    # the history and on disk alike
+    for h, r in zip(hist, recs):
+        assert h["time_s"] > 0 and r["time_s"] == h["time_s"]
+        assert "tokens_per_s" not in r
 
 
 def test_single_process_glue_degrades():
