@@ -208,10 +208,13 @@ def test_backward_bf16_params_f32_accum():
 
 
 @pytest.mark.parametrize("B,S,NQ,NKV,H", [
-    (1, 128, 4, 4, 64), (2, 256, 4, 2, 64), (1, 384, 8, 1, 128)])
+    (1, 128, 4, 4, 64), (2, 256, 4, 2, 64), (1, 384, 8, 1, 128),
+    (1, 1152, 8, 4, 32),         # three 384-row blocks, 4 heads a program
+    (1, 2048, 2, 2, 64)])        # two 1024-row blocks
 @pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
 @pytest.mark.parametrize("causal,window", [
-    (True, 0), (True, 128), (False, 0)])
+    (True, 0), (True, 128), (False, 0),
+    (True, 767)])   # under a 1024 block; at 384, one tile's last key is the edge
 def test_flash_attention_sweep(B, S, NQ, NKV, H, dtype, causal, window):
     rng = np.random.default_rng(S + NQ)
     q = jnp.asarray(rng.standard_normal((B, S, NQ, H)) * 0.4, dtype)
@@ -225,6 +228,34 @@ def test_flash_attention_sweep(B, S, NQ, NKV, H, dtype, causal, window):
                               causal=causal, window=window)
     np.testing.assert_allclose(np.asarray(o, np.float32), np.asarray(orf),
                                **_tol(dtype))
+
+
+@pytest.mark.parametrize("B,S,N,H,window", [
+    (8, 2048, 12, 64, 0),        # gpt-moe-s at the benchmark cell's batch
+    (1, 8192, 16, 256, 4096),    # a Gemma-2 local layer
+    (2, 4096, 12, 64, 1026)])    # its first key lies one past a block edge
+def test_flash_attention_tiles_fetch_only_the_band(B, S, N, H, window):
+    """The grid is at most 1/16 of the 128 x 128, one-head grid, and,
+    walking it in order, each q block copies in each K/V block of its
+    band exactly once and no block outside it: the pipeline copies a
+    block when the index map names another block than the step before."""
+    from repro.kernels import flash_attention as fa
+    bq, bk, hb = fa.tile_sizes(S, S, N, H, 2)
+    nq, nk = S // bq, S // bk
+    assert B * (N // hb) * nq * nk * 16 <= B * N * (S // 128) ** 2
+    kv_map = fa.kv_index_map(bq=bq, bk=bk, nk=nk, causal=True,
+                             window=window)
+    pos = np.arange(S)
+    for qi in range(nq):
+        qpos = pos[qi * bq:(qi + 1) * bq, None]
+        band = [ki for ki in range(nk)
+                if ((pos[None, ki * bk:(ki + 1) * bk] <= qpos)
+                    & ((window == 0)
+                       | (pos[None, ki * bk:(ki + 1) * bk] > qpos - window))
+                    ).any()]
+        idx = [int(kv_map(0, 0, qi, ki)[2]) for ki in range(nk)]
+        runs = [i for j, i in enumerate(idx) if j == 0 or i != idx[j - 1]]
+        assert runs == band, (qi, runs, band)
 
 
 # ---------------------------------------------------------------------------

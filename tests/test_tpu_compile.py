@@ -113,7 +113,14 @@ def test_paged_decode_attention_compiles(one_chip, nq, nkv, hd):
         sds((b,), jnp.int32))
 
 
-def test_flash_attention_compiles(one_chip):
-    qkv = jax.ShapeDtypeStruct((1, 2048, 12, 64), jnp.bfloat16,
-                               sharding=one_chip)
-    assert "flash_attention" in _compile(fa.flash_attention, qkv, qkv, qkv)
+@pytest.mark.parametrize("shape,window", [
+    ((8, 2048, 12, 64), 0),        # gpt-moe-s at the benchmark cell's batch
+    ((1, 4096, 16, 128), 0),       # olmoe-1b-7b widths
+    ((1, 8192, 16, 256), 4096)])   # a Gemma-2 local (sliding-window) layer
+def test_flash_attention_compiles(one_chip, shape, window):
+    qkv = jax.ShapeDtypeStruct(shape, jnp.bfloat16, sharding=one_chip)
+
+    def fwd(q, k, v):
+        return fa.flash_attention(q, k, v, causal=True, window=window)
+
+    assert "flash_attention" in _compile(fwd, qkv, qkv, qkv)
