@@ -47,17 +47,18 @@ def readings(cell, devices, seeds, stand_ins: bool, out=sys.stdout):
         prog = setup.check_steps()
         setup.state.clear()
         cap = tc.capacity_of(setup)
+        on = {"groups": cell.chips, "devices": devices}
         ref = tc.reference_readings(cell.config, setup.opt, cap, setup.key,
-                                    prog["batches"])
+                                    prog["batches"], **on)
         row = {"seed": seed, "program": tc.gaps(prog, ref),
                "losses": prog["losses"], "ref_losses": ref["losses"]}
         if stand_ins:
             ctl = tc.reference_readings(cell.config, setup.opt, cap,
                                         setup.key, prog["batches"],
-                                        quant="fp8")
+                                        quant="fp8", **on)
             half = [b[: b.shape[0] // 2] for b in prog["batches"]]
             hb = tc.reference_readings(cell.config, setup.opt, cap,
-                                       setup.key, half)
+                                       setup.key, half, **on)
             row["control"] = tc.gaps(ctl, ref)
             row["half_batch"] = tc.gaps(hb, ref)
         row["seconds"] = time.perf_counter() - t

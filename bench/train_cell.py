@@ -24,8 +24,14 @@ steps are compared with it:
   whose reference gradient is under a thousandth of the median leaf's.
 
 A gap is measured against the reference's norm of that leaf or of the
-median leaf, whichever is larger.  The limits are in the configuration
-file, under ``limits.train``.
+median leaf, whichever is larger.  Every program leaf has its reference
+counterpart (``LEAF_MAP``) and the reverse, or the check raises.  The
+limits are in the configuration file, under ``limits.train``.
+
+The reference drops by GShard's group rule, one group per chip, since
+the program shards the flat tokens over the cell's chips in order, and
+runs over those chips: its weights, moments and gradients split on
+their expert, head or vocabulary axis (``SPLIT_AXIS``).
 """
 from __future__ import annotations
 
@@ -55,6 +61,8 @@ LEAF_MAP = {
     "blocks/l0/attn/wk": ("wk",),
     "blocks/l0/attn/wv": ("wv",),
     "blocks/l0/attn/wo": ("wo",),
+    "blocks/l0/attn/q_norm/scale": ("q_norm",),
+    "blocks/l0/attn/k_norm/scale": ("k_norm",),
     "blocks/l0/ln2/scale": ("ln2",),
     "final_norm/scale": ("final_norm",),
     "router": ("router",),
@@ -62,10 +70,21 @@ LEAF_MAP = {
 }
 
 
-# the configuration file's keys that set the program's ModelConfig
+# the configuration file's keys that set the program's ModelConfig, and
+# those passed only where the file gives them (each defaults to the
+# program's behaviour before it had the field; ``moe`` passes whole)
 PROGRAM_KEYS = ("num_layers", "d_model", "num_heads", "num_kv_heads",
                 "head_dim", "vocab_size", "act", "norm", "tie_embeddings",
                 "rope_theta", "dtype", "param_dtype")
+OPTIONAL_KEYS = ("qk_norm", "norm_eps")
+# the axis each reference leaf is split on over the cell's chips: its
+# experts, heads or vocabulary.  A leaf not named here (a norm scale) is
+# replicated, and so is one whose axis the chips do not divide.  Split on
+# d_model, the partitioner shards the activations on it too, and the
+# TPU compiler fails a consistency check on the experts' combine.
+SPLIT_AXIS = {"router": 2, "wi": 1, "wg": 1, "wo_e": 1,
+              "wq": 2, "wk": 2, "wv": 2, "wo": 1,
+              "embed": 0, "unembed": 1}
 
 
 class WindowClosed(Exception):
@@ -80,16 +99,30 @@ def seed_key(seed: int):
 
 
 # ------------------------------------------------------------- the program
+def _require_fields(obj, keys, prefix: str):
+    import dataclasses
+    have = {f.name for f in dataclasses.fields(obj)}
+    for k in keys:
+        if k not in have:
+            raise harness.BenchError(
+                f"the configuration file sets {prefix}{k}, and the "
+                f"program's {type(obj).__name__} has no field {k!r}")
+
+
 def program_config(cfg: Dict):
     """The program's ModelConfig as the configuration file ``cfg`` states
     it: the program's own preset ``program_arch`` with every size and
-    setting the file gives."""
+    setting the file gives.  A setting the program has no field for
+    raises BenchError."""
     import dataclasses
     import repro.configs as configs
     pcfg = configs.get(cfg["program_arch"])
+    keys = PROGRAM_KEYS + tuple(k for k in OPTIONAL_KEYS if k in cfg)
+    _require_fields(pcfg, keys, "")
+    _require_fields(pcfg.moe, cfg["moe"], "moe.")
     return pcfg.replace(
         moe=dataclasses.replace(pcfg.moe, **cfg["moe"]),
-        **{k: cfg[k] for k in PROGRAM_KEYS})
+        **{k: cfg[k] for k in keys})
 
 
 def program_params(pcfg, canon: Dict, ep: int) -> Dict:
@@ -109,11 +142,15 @@ def program_params(pcfg, canon: Dict, ep: int) -> Dict:
     embed = {"embedding": canon["embed"]}
     if "unembed" in canon:
         embed["unembed"] = canon["unembed"]
+    attn = {k: canon[k] for k in ("wq", "wk", "wv", "wo")}
+    for k in ("q_norm", "k_norm"):
+        if k in canon:
+            attn[k] = {"scale": canon[k]}
     return {
         "embed": embed,
         "blocks": {"l0": {
             "ln1": {"scale": canon["ln1"]},
-            "attn": {k: canon[k] for k in ("wq", "wk", "wv", "wo")},
+            "attn": attn,
             "ln2": {"scale": canon["ln2"]}}},
         "final_norm": {"scale": canon["final_norm"]},
         "router": canon["router"],
@@ -318,19 +355,45 @@ def run_window(setup: Setup, seconds: float, trace_dir=None) -> Dict:
 
 
 # -------------------------------------------------------------- the check
+def reference_shardings(cfg: Dict, devices) -> Dict:
+    """Each reference leaf's layout over a one-axis mesh of ``devices``:
+    split on its ``SPLIT_AXIS`` where the devices divide it, else
+    replicated."""
+    import jax
+    from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
+    mesh = Mesh(np.array(devices), ("ref",))
+    n = len(devices)
+    shapes = jax.eval_shape(lambda k: reference.init_params(cfg, k),
+                            jax.random.PRNGKey(0))
+    out = {}
+    for name, leaf in shapes.items():
+        spec = [None] * leaf.ndim
+        axis = SPLIT_AXIS.get(name)
+        if axis is not None and leaf.shape[axis] % n == 0:
+            spec[axis] = "ref"
+        out[name] = NamedSharding(mesh, P(*spec))
+    return out
+
+
 def reference_readings(cfg: Dict, opt: Dict, cap: int, key, batches,
-                       quant=None) -> Dict:
+                       quant=None, groups: int = 1, devices=None) -> Dict:
     """The reference's losses, clipped first-step gradient norms and
     change norms over ``len(batches)`` steps, keyed as the program's
-    leaves."""
+    leaves.  ``cap`` assignments are kept per (group, expert); the
+    reference runs over ``devices`` (the default device when None)."""
     import jax
     import jax.numpy as jnp
-    step = jax.jit(reference.make_train_step(cfg, opt, cap, quant),
+    shard = reference_shardings(cfg, devices or jax.devices()[:1])
+    step = jax.jit(reference.make_train_step(cfg, opt, cap, quant, groups),
+                   in_shardings=(shard, shard, shard, None, None),
+                   out_shardings=(shard, shard, shard, None, shard),
                    donate_argnums=(0, 1, 2))
-    init = jax.jit(lambda k: reference.init_params(cfg, k))
+    init = jax.jit(lambda k: reference.init_params(cfg, k),
+                   out_shardings=shard)
+    zeros = jax.jit(lambda p: jax.tree.map(jnp.zeros_like, p),
+                    out_shardings=shard)
     params = init(key)
-    mu = jax.tree.map(jnp.zeros_like, params)
-    nu = jax.tree.map(jnp.zeros_like, params)
+    mu, nu = zeros(params), zeros(params)
     sq = jax.jit(lambda t: {k: jnp.sum(jnp.square(v)) for k, v in t.items()})
     losses, grads_sq = [], None
     for i, toks in enumerate(batches):
@@ -349,6 +412,11 @@ def reference_readings(cfg: Dict, opt: Dict, cap: int, key, batches,
 
 
 def _group(sq: Dict) -> Dict:
+    """Reference leaves' squared norms -> program leaves' norms."""
+    mapped = {k for keys in LEAF_MAP.values() for k in keys}
+    if set(sq) - mapped:
+        raise harness.BenchError(f"reference leaves with no program leaf: "
+                                 f"{sorted(set(sq) - mapped)}")
     out = {}
     for leaf, keys in LEAF_MAP.items():
         have = [sq[k] for k in keys if k in sq]
@@ -359,7 +427,15 @@ def _group(sq: Dict) -> Dict:
 
 def gaps(prog: Dict, ref: Dict) -> Dict:
     """The three numbers compared, from the program's (or a stand-in's)
-    readings and the reference's."""
+    readings and the reference's.  A leaf on one side only raises
+    BenchError: no leaf goes unchecked."""
+    for what in ("grad_norms", "delta_norms"):
+        mine, theirs = set(prog[what]), set(ref[what])
+        if mine != theirs:
+            raise harness.BenchError(
+                f"{what}: program leaves with no reference counterpart "
+                f"{sorted(mine - theirs)}, reference leaves with no "
+                f"program counterpart {sorted(theirs - mine)}")
     loss_gap = max(abs(a - b) / abs(b) for a, b in
                    zip(prog["losses"], ref["losses"]))
     gref = ref["grad_norms"]
@@ -389,6 +465,8 @@ def judge(g: Dict, limits: Dict):
 
 
 def capacity_of(setup: Setup) -> int:
+    """The program's capacity, per (device pair, compute slot), which is
+    the reference's per (group, expert) with one group per chip."""
     mix = setup.mix
     return reference.capacity(setup.cfg, mix["global_batch"]
                               * mix["seq_len"] // setup.chips, setup.ep)
@@ -436,7 +514,8 @@ def run(cell, *, devices, peaks, seed: int, seconds: float, trace: bool,
 
     t = time.perf_counter()
     ref = reference_readings(cell.config, setup.opt, capacity_of(setup),
-                             setup.key, prog["batches"])
+                             setup.key, prog["batches"], groups=cell.chips,
+                             devices=devices)
     print(json.dumps({"phase": "reference",
                       "seconds": time.perf_counter() - t}), flush=True)
     checks, correct = judge(gaps(prog, ref), cell.config["limits"]["train"])
